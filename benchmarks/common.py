@@ -28,6 +28,9 @@ def run_with_devices(code: str, devices: int = 512, timeout: int = 900) -> dict:
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     env.pop("XLA_FLAGS", None)
+    # fake devices are CPU devices: the child never reaches for a chip the
+    # parent may hold
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run([sys.executable, "-c", prelude + textwrap.dedent(code)],
                           capture_output=True, text=True, timeout=timeout,
                           env=env)

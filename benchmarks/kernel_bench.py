@@ -50,8 +50,9 @@ def _ref_paths() -> None:
     # sparse hot path: PS pull (embed_gather) / push (embed_scatter_add).
     # Interpret-mode wall time is meaningless, so we time the jnp reference
     # (what a TPU-less run executes) and report the kernel's analytic DMA
-    # working set: ids live in SMEM, one (1, E) row block moves per grid
-    # step — n_ids·E·itemsize streamed, never the (Vs, E) table.
+    # working set: ids live in SMEM, one tile-aligned (8, E) f32 row group
+    # moves per grid step — n_ids·8·E·itemsize streamed, never the (Vs, E)
+    # table.
     for (vs, e, n) in [(4096, 512, 1024), (32768, 1024, 4096)]:
         ks = jax.random.split(jax.random.key(2), 3)
         table = jax.random.normal(ks[0], (vs, e), jnp.float32)
@@ -61,11 +62,12 @@ def _ref_paths() -> None:
         gfn = jax.jit(lambda t, i: ref.embed_gather_ref(t, i, 0))
         sec = time_fn(gfn, table, ids)
         emit(f"kernels/embed_gather_ref/v{vs}e{e}n{n}", sec * 1e6,
-             f"dma_kb={n * e * 4 / 1024:.0f};ids_smem_kb={n * 4 / 1024:.0f}")
+             f"dma_kb={n * 8 * e * 4 / 1024:.0f};"
+             f"ids_smem_kb={n * 4 / 1024:.0f}")
         sfn = jax.jit(lambda i, r: ref.embed_scatter_add_ref(i, r, vs))
         sec = time_fn(sfn, uids, rows)
         emit(f"kernels/embed_scatter_ref/v{vs}e{e}n{n}", sec * 1e6,
-             f"dma_kb={n * e * 4 / 1024:.0f};blocks=1x{e}")
+             f"dma_kb={n * e * 4 / 1024:.0f};blocks=8x{e}")
     for (b, s, h, e) in [(2, 512, 4, 64)]:
         ks = jax.random.split(jax.random.key(1), 5)
         r = jax.random.normal(ks[0], (b, s, h, e), jnp.float32)

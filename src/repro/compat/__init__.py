@@ -1,6 +1,6 @@
-"""repro.compat — version-portable sharding/mesh layer.
+"""repro.compat — the sharding/mesh names, spelled for JAX 0.9.0.
 
-The single import point for anything whose spelling changed across JAX
+The single import point for anything whose spelling has changed across JAX
 generations. Library code, launchers, benchmarks, and the test subprocess
 snippets all use these names; ``jax.sharding.AxisType`` / ``jax.set_mesh`` /
 ``jax.shard_map`` must never be imported directly outside this package
@@ -10,22 +10,15 @@ snippets all use these names; ``jax.sharding.AxisType`` / ``jax.set_mesh`` /
     mesh = make_mesh((2, 4), ("data", "model"))
     with use_mesh(mesh):
         ...
-
-Capability probes (``has_explicit_sharding()`` etc.) let call sites choose
-between explicit-sharding and shard_map/pjit code paths at runtime.
 """
 from repro.compat.version import (MIN_SUPPORTED, capabilities,
-                                  has_axis_types, has_explicit_sharding,
-                                  has_set_mesh, has_top_level_shard_map,
-                                  has_use_mesh, jax_version_tuple, supported)
+                                  jax_version_tuple, supported)
 from repro.compat.shardmesh import (AxisType, Mesh, NamedSharding, P,
                                     PartitionSpec, cost_analysis, make_mesh,
-                                    named_sharding, shard_map, use_mesh)
+                                    shard_map, use_mesh)
 
 __all__ = [
-    "MIN_SUPPORTED", "capabilities", "has_axis_types",
-    "has_explicit_sharding", "has_set_mesh", "has_top_level_shard_map",
-    "has_use_mesh", "jax_version_tuple", "supported",
+    "MIN_SUPPORTED", "capabilities", "jax_version_tuple", "supported",
     "AxisType", "Mesh", "NamedSharding", "P", "PartitionSpec",
-    "cost_analysis", "make_mesh", "named_sharding", "shard_map", "use_mesh",
+    "cost_analysis", "make_mesh", "shard_map", "use_mesh",
 ]

@@ -1,13 +1,11 @@
-"""Pallas naming drift.
+"""Pallas names the kernels import from one place.
 
-``pltpu.CompilerParams`` (new JAX) was ``pltpu.TPUCompilerParams`` on 0.4.x;
-the constructor signature (dimension_semantics, vmem_limit_bytes, ...) is the
-same. Kernels import the alias from here instead of pltpu directly.
+Kernels take ``CompilerParams`` from here instead of from pltpu directly,
+so a later JAX that renames it changes this file only.
 """
 from __future__ import annotations
 
 from jax.experimental import pallas as pl  # noqa: F401  (re-export)
 from jax.experimental.pallas import tpu as pltpu  # noqa: F401  (re-export)
 
-CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
+CompilerParams = pltpu.CompilerParams

@@ -1,110 +1,44 @@
-"""Version-portable mesh/sharding constructors.
+"""Mesh/sharding constructors, spelled for JAX 0.9.
 
 Every mesh, sharding-context, or shard_map construction in this repo goes
-through here instead of calling ``jax.*`` directly, so the same source runs
-on JAX 0.4.x (check_rep / no AxisType) and on 0.6/0.7+ (check_vma /
-AxisType / set_mesh). Branches are driven by the probes in
-``repro.compat.version`` — monkeypatch those to exercise a fallback path on
-any installed JAX.
+through here instead of calling ``jax.*`` directly, so a later JAX that
+renames one of them changes this file only.
 """
 from __future__ import annotations
 
 import contextlib
-import enum
 from typing import Any, Optional, Sequence
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec
-
-from repro.compat import version
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec
 
 P = PartitionSpec
-
-if version.has_axis_types():
-    from jax.sharding import AxisType
-else:
-    class AxisType(enum.Enum):
-        """Stand-in for jax.sharding.AxisType on JAX < 0.6. Only carries
-        identity: pre-explicit-sharding JAX treats every axis as Auto, so
-        the values are accepted (and Auto is a no-op) but never forwarded."""
-        Auto = "auto"
-        Explicit = "explicit"
-        Manual = "manual"
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str],
               axis_types: Optional[tuple] = None,
               devices: Optional[Sequence] = None) -> Mesh:
-    """jax.make_mesh that works on every supported JAX.
-
-    ``axis_types`` defaults to all-Auto; on JAX without AxisType the
-    argument is dropped (Auto is that JAX's only behavior). Requesting
-    Explicit axes on a JAX that cannot honor them is an error, not a
-    silent downgrade."""
-    shape, axes = tuple(shape), tuple(axes)
-    if version.has_axis_types():
-        if axis_types is None:
-            axis_types = (AxisType.Auto,) * len(axes)
-        return jax.make_mesh(
-            shape, axes, axis_types=tuple(axis_types),
-            **({"devices": devices} if devices is not None else {}))
-    if axis_types is not None and any(
-            getattr(t, "name", str(t)) == "Explicit" for t in axis_types):
-        raise NotImplementedError(
-            f"explicit sharding axes requested on JAX {jax.__version__} "
-            "(no jax.sharding.AxisType); gate on "
-            "repro.compat.has_explicit_sharding()")
-    if hasattr(jax, "make_mesh"):
-        return jax.make_mesh(
-            shape, axes,
-            **({"devices": devices} if devices is not None else {}))
-    from jax.experimental import mesh_utils
-    devs = mesh_utils.create_device_mesh(shape, devices=devices)
-    return Mesh(devs, axes)
+    """``jax.make_mesh`` with all-Auto axis types unless told otherwise."""
+    if axis_types is None:
+        axis_types = (AxisType.Auto,) * len(axes)
+    return jax.make_mesh(
+        tuple(shape), tuple(axes), axis_types=tuple(axis_types),
+        **({"devices": devices} if devices is not None else {}))
 
 
 @contextlib.contextmanager
 def use_mesh(mesh: Mesh):
-    """``with jax.set_mesh(mesh)`` where it exists, else a no-op context.
-
-    Pre-explicit-sharding JAX needs no ambient mesh: this repo passes the
-    mesh explicitly everywhere (NamedSharding in_shardings, shard_map
-    ``mesh=``), so the fallback yields without touching global state."""
-    if version.has_set_mesh():
-        with jax.set_mesh(mesh):
-            yield mesh
-    elif version.has_use_mesh():
-        with jax.sharding.use_mesh(mesh):
-            yield mesh
-    else:
+    """``with jax.set_mesh(mesh)``, yielding the mesh."""
+    with jax.set_mesh(mesh):
         yield mesh
 
 
 def shard_map(f, *, mesh: Mesh, in_specs: Any, out_specs: Any,
               check_vma: bool = True):
-    """jax.shard_map portable over the check_vma -> check_rep rename and
-    the experimental -> top-level move."""
-    if version.has_top_level_shard_map():
-        try:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=check_vma)
-        except TypeError:
-            # 0.5/0.6 window: top-level name, pre-rename kwarg
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_vma)
-
-
-def named_sharding(mesh: Mesh, spec: PartitionSpec) -> NamedSharding:
-    return NamedSharding(mesh, spec)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def cost_analysis(compiled) -> dict:
-    """Compiled.cost_analysis() normalized: JAX 0.4.x returns a one-element
-    list of dicts (per program), newer JAX returns the dict itself."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca or {}
+    """``Compiled.cost_analysis()``, with ``{}`` where XLA reports none."""
+    return compiled.cost_analysis() or {}

@@ -1,24 +1,15 @@
-"""JAX capability probes for the version-portable sharding layer.
+"""The one supported JAX and a report of what is installed.
 
-Everything here is a FUNCTION (re-evaluated per call, monkeypatch-friendly)
-so tests can force the fallback paths without installing another JAX.
-
-Supported range: JAX 0.4.30 – current. Two API generations matter:
-
-  * 0.4.x          ``jax.make_mesh(shape, names)`` (no ``axis_types``),
-                   ``jax.experimental.shard_map.shard_map(check_rep=...)``,
-                   no ``jax.set_mesh`` / ``jax.sharding.AxisType``.
-  * 0.6/0.7+       ``jax.make_mesh(..., axis_types=...)``, ``jax.set_mesh``,
-                   top-level ``jax.shard_map(check_vma=...)``, explicit
-                   sharding mode via ``AxisType.Explicit``.
-
-Callers must branch on the probes below, never on version literals.
+The repo targets exactly JAX 0.9.0 (``MIN_SUPPORTED``): its spellings are
+the ones ``repro.compat`` re-exports, and nothing here branches on another
+version. ``supported()`` lets tools/check_env.py flag an install that is
+older than that.
 """
 from __future__ import annotations
 
 import jax
 
-MIN_SUPPORTED = (0, 4, 30)
+MIN_SUPPORTED = (0, 9, 0)
 
 
 def jax_version_tuple() -> tuple:
@@ -38,46 +29,15 @@ def jax_version_tuple() -> tuple:
     return tuple(parts)
 
 
-def has_axis_types() -> bool:
-    """jax.sharding.AxisType + make_mesh(axis_types=...) exist."""
-    return hasattr(jax.sharding, "AxisType")
-
-
-def has_set_mesh() -> bool:
-    """jax.set_mesh context manager exists."""
-    return hasattr(jax, "set_mesh")
-
-
-def has_use_mesh() -> bool:
-    """jax.sharding.use_mesh (the pre-set_mesh spelling) exists."""
-    return hasattr(jax.sharding, "use_mesh")
-
-
-def has_top_level_shard_map() -> bool:
-    """jax.shard_map (check_vma generation) exists."""
-    return hasattr(jax, "shard_map")
-
-
-def has_explicit_sharding() -> bool:
-    """True when the explicit-sharding programming model (AxisType +
-    set_mesh) is available; consumers then may use sharding-in-types code
-    paths instead of shard_map/pjit."""
-    return has_axis_types() and (has_set_mesh() or has_use_mesh())
-
-
 def supported() -> bool:
     return jax_version_tuple() >= MIN_SUPPORTED
 
 
 def capabilities() -> dict:
-    """One-stop capability report (tools/check_env.py, debugging)."""
+    """Installed-JAX report (tools/check_env.py, debugging)."""
     return {
         "jax_version": jax.__version__,
         "jax_version_tuple": list(jax_version_tuple()),
+        "min_supported": list(MIN_SUPPORTED),
         "supported": supported(),
-        "axis_types": has_axis_types(),
-        "set_mesh": has_set_mesh(),
-        "use_mesh": has_use_mesh(),
-        "top_level_shard_map": has_top_level_shard_map(),
-        "explicit_sharding": has_explicit_sharding(),
     }

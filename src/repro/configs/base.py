@@ -176,7 +176,7 @@ class RunConfig:
     bucket_bytes: int = 4 * 1024 * 1024
     # embedding gather/scatter implementation for the sparse hot path:
     # jnp (take/scatter-add) | pallas (kernels/embed_gather + embed_scatter,
-    # interpret-mode off-TPU)
+    # interpret mode on the CPU)
     embed_impl: str = "jnp"
     # per-message collective latency override (seconds) for the planner's
     # α + β·b argmin; None = utils/roofline.py HW.link_latency. 0 recovers

@@ -65,7 +65,7 @@ from repro.compat import P, shard_map
 from repro.core import cost_model, embedding
 from repro.core.plan import ParamPlan, Plan, plan_leaves
 from repro.core.runtime import manual_region
-from repro.utils.roofline import HW
+from repro.utils.roofline import HW, device_hw
 
 
 def _plan_leaves(plan: Plan) -> list[ParamPlan]:
@@ -120,7 +120,7 @@ class BucketPlan:
         the same hardware model the planner's argmin used. Each bucket is
         priced at its chosen execution schedule; the unbucketed reference
         is one flat ring per member tensor."""
-        hw = hw or self.hw or HW
+        hw = hw or self.hw or device_hw()
         dims = self.dims
         ring = 2.0 * (self.replicas - 1) / max(self.replicas, 1)
         tier = cost_model.span_tier(dims, hw)

@@ -50,7 +50,7 @@ import os
 from dataclasses import dataclass, replace
 from typing import Optional
 
-from repro.utils.roofline import HW, Hardware
+from repro.utils.roofline import HW, Hardware, device_hw
 
 # Hardware fields a fitted hw_profile.json (tools/profile_collectives.py
 # fit) may override; anything else in the file is ignored.
@@ -78,13 +78,13 @@ def load_hw_profile(path: str, hw: Optional[Hardware] = None) -> Hardware:
 
 
 def resolve_hw(run_cfg=None, hw: Optional[Hardware] = None) -> Hardware:
-    """The hardware model the planner prices against: the roofline HW,
-    overlaid with RunConfig.hw_profile (a fitted α₁β₁/α₂β₂ profile from
+    """The hardware model the planner prices against: the chip's roofline
+    entry (``roofline.device_hw``), overlaid with RunConfig.hw_profile (a fitted α₁β₁/α₂β₂ profile from
     tools/profile_collectives.py) when set, then RunConfig.link_latency
     (when set) overriding the intra α term — the config path for pinning
     the pure-byte Table-3 argmin (link_latency=0) without mutating module
     state."""
-    hw = hw or HW
+    hw = hw or device_hw()
     prof = getattr(run_cfg, "hw_profile", None) if run_cfg is not None else None
     if prof:
         hw = load_hw_profile(prof, hw)

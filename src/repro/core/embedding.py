@@ -151,7 +151,7 @@ def _gather_rows(table_shard, local_ids, ctx: EmbedCtx):
     """Owned-row pull: rows for local-space ids in [0, Vs), zeros elsewhere.
 
     The per-shard half of the PS pull — either the Pallas embed_gather
-    kernel (ids in SMEM drive the table DMA; interpret-mode off-TPU) or its
+    kernel (ids in SMEM drive the table DMA; interpret mode on the CPU) or its
     jnp oracle (kernels/ref.py — one source of truth for the take+mask
     semantics), per ``RunConfig.embed_impl``.
     """

@@ -257,11 +257,12 @@ def kv_local_favorable(rt, cfg) -> bool:
     saved compute/chip ≈ 4 passes · 2·T·D·KVdim·(m-1)/m / peak
     added wire/chip    ≈ 3 units · 2·T·KVdim·wire_bytes·(m-1)/m / link_bw
     """
-    from repro.utils.roofline import HW
+    from repro.utils.roofline import device_hw
+    hw = device_hw()
     m = rt.mesh.shape["model"]
     d, kvdim = cfg.d_model, cfg.kv_dim
-    saved = 4 * 2 * d * kvdim * (m - 1) / m / HW.peak_flops
-    added = 3 * 2 * kvdim * (m - 1) / m / HW.link_bw
+    saved = 4 * 2 * d * kvdim * (m - 1) / m / hw.peak_flops
+    added = 3 * 2 * kvdim * (m - 1) / m / hw.link_bw
     # SP-TP training lives near the collective roof: wire seconds are worth
     # ~2x compute seconds unless compute clearly dominates (hypothesis log,
     # §Perf iteration A2: confirmed on mistral/command-r, refuted on phi3

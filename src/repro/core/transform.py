@@ -45,7 +45,6 @@ from repro.models.model import Model, build_model
 from repro.optim.optimizer import (Optimizer, TrainState, fuse_state,
                                    is_fused, make_optimizer, unfuse_state)
 from repro.utils.tree import named_leaves, path_name as tree_path_name
-from repro.utils.roofline import HW
 
 
 def _mesh_dims(mesh: Optional[Mesh], rules: MeshRules) -> cost_model.MeshDims:
@@ -69,7 +68,7 @@ def estimate_census(model: Model, rt: Runtime) -> sparsity.Census:
 
 
 def analyze(model: Model, rt: Runtime,
-            memory_budget: float = 0.9 * HW.hbm_bytes,
+            memory_budget: Optional[float] = None,
             census: Optional[sparsity.Census] = None,
             stale_tables: tuple = ()) -> Plan:
     """Census + cost model -> Plan (the paper's analysis phase).
@@ -78,7 +77,8 @@ def analyze(model: Model, rt: Runtime,
     from measured sparsity; by default the workload-model estimate is used.
     ``stale_tables`` names sparse tables running the bounded-staleness push
     (the jitter fallback) — stamped onto the plan so the train step builds
-    the stale update rule for exactly those tables.
+    the stale update rule for exactly those tables. ``memory_budget``
+    defaults to 90% of the chip's HBM.
     """
     if census is None:
         census = estimate_census(model, rt)
@@ -87,13 +87,15 @@ def analyze(model: Model, rt: Runtime,
 
 
 def choose_methods(model: Model, rt: Runtime, census: sparsity.Census,
-                   memory_budget: float = 0.9 * HW.hbm_bytes,
+                   memory_budget: Optional[float] = None,
                    stale_tables: tuple = ()) -> Plan:
     """Stage 2: pure census -> Plan (Table-3 argmin + memory escalation)."""
     specs = model.specs()
     dims = _mesh_dims(rt.mesh, rt.rules)
     comm_mode = rt.run_cfg.comm_mode
     hw = cost_model.resolve_hw(rt.run_cfg)
+    if memory_budget is None:
+        memory_budget = 0.9 * hw.hbm_bytes
 
     can_shard_rows = rt.rules.axis_size("vocab") > 1
     strategy = getattr(rt, "resolved_strategy", rt.run_cfg.dense_strategy)
@@ -635,9 +637,8 @@ def build_step(model: Model, optimizer: Optimizer, rt: Runtime, plan: Plan,
     state_like = state
     if plan.mesh is not None:
         # every sharding below names the mesh explicitly, so the pjit path
-        # needs no ambient mesh; on explicit-sharding JAX use_mesh gives
-        # callers who didn't wrap the builder the set_mesh placement
-        # semantics, and on older JAX it is a no-op context.
+        # needs no ambient mesh; use_mesh gives callers who didn't wrap the
+        # builder the set_mesh placement semantics.
         with compat.use_mesh(plan.mesh):
             shardings = state_shardings(plan, state_like)
             state = jax.device_put(state, shardings)

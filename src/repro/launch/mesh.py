@@ -2,8 +2,7 @@
 
 Defined as FUNCTIONS (never module-level constants) so importing this module
 never touches jax device state — smoke tests keep their single device.
-Construction goes through repro.compat so the same launchers run on every
-supported JAX (see src/repro/compat/).
+Construction goes through repro.compat (see src/repro/compat/).
 """
 from __future__ import annotations
 
@@ -94,13 +93,8 @@ def shrink_mesh(mesh: Optional[Mesh], drop_axis_index: Optional[int] = None,
         return None
     kept = np.delete(devices, drop_axis_index, axis=ax)
     # the Mesh constructor (via repro.compat) takes the device grid as-is —
-    # no re-layout, unlike the make_mesh convenience path. Axis types carry
-    # over where the installed JAX has them (pre-AxisType JAX has neither
-    # the attribute nor the kwarg, and Auto is its only behavior)
-    axis_types = getattr(mesh, "axis_types", None)
-    if axis_types is not None:
-        return Mesh(kept, mesh.axis_names, axis_types=axis_types)
-    return Mesh(kept, mesh.axis_names)
+    # no re-layout, unlike the make_mesh convenience path
+    return Mesh(kept, mesh.axis_names, axis_types=mesh.axis_types)
 
 
 def grow_mesh(mesh: Optional[Mesh], slice_devices,
@@ -148,7 +142,4 @@ def grow_mesh(mesh: Optional[Mesh], slice_devices,
             f"returning slice overlaps the live mesh: device ids "
             f"{sorted(overlap)}")
     grown = np.insert(devices, insert_axis_index, new, axis=ax)
-    axis_types = getattr(mesh, "axis_types", None)
-    if axis_types is not None:
-        return Mesh(grown, mesh.axis_names, axis_types=axis_types)
-    return Mesh(grown, mesh.axis_names)
+    return Mesh(grown, mesh.axis_names, axis_types=mesh.axis_types)

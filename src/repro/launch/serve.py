@@ -7,12 +7,22 @@
 per admission, slot-paged decode, device-side sampling. ``--engine toy``
 runs the teacher-forced baseline loop (also the fallback for recurrent
 families, whose carry cannot be bucket-prefilled under padding).
+``main(argv)`` parses its own arguments; ``--devices N`` runs on N fake CPU
+devices and has to be given before the process has touched a JAX backend.
 """
 import argparse
-import os
+import time
+
+import jax
+import numpy as np
+
+from repro.configs import RunConfig, get_config, reduced
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_mesh
+from repro.runtime.server import Request, Server, ServerConfig, ToyServer
 
 
-def _parse():
+def _parse(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="phi3-medium-14b")
     ap.add_argument("--reduced", action="store_true", default=True)
@@ -25,33 +35,22 @@ def _parse():
     ap.add_argument("--sample", action="store_true",
                     help="temperature sampling instead of greedy argmax")
     ap.add_argument("--temperature", type=float, default=1.0)
-    ap.add_argument("--devices", type=int, default=0)
+    ap.add_argument("--devices", type=int, default=0,
+                    help="run on this many fake CPU devices (no chip)")
     ap.add_argument("--mesh", default="")
     ap.add_argument("--seed", type=int, default=0)
-    return ap.parse_args()
+    return ap.parse_args(argv)
 
 
-ARGS = _parse()
-if ARGS.devices:
-    os.environ["XLA_FLAGS"] = (
-        f"--xla_force_host_platform_device_count={ARGS.devices} "
-        + os.environ.get("XLA_FLAGS", ""))
-
-import time  # noqa: E402
-import jax  # noqa: E402
-import numpy as np  # noqa: E402
-
-from repro import compat  # noqa: E402
-from repro.configs import RunConfig, get_config, reduced  # noqa: E402
-from repro.launch.mesh import make_mesh  # noqa: E402
-from repro.runtime.server import (Request, Server, ServerConfig,  # noqa: E402
-                                  ToyServer)
-
-
-def main():
-    args = ARGS
-    print(f"jax {jax.__version__}  devices={jax.device_count()}  "
-          f"explicit_sharding={compat.has_explicit_sharding()}")
+def main(argv=None):
+    args = _parse(argv)
+    if args.devices:
+        jax.config.update("jax_platforms", "cpu")
+        jax.config.update("jax_num_cpu_devices", args.devices)
+    enable_compile_cache()
+    dev = jax.devices()[0]
+    print(f"jax {jax.__version__}  platform={dev.platform}  "
+          f"kind={dev.device_kind}  devices={jax.device_count()}")
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduced(cfg)

@@ -4,16 +4,24 @@
       --steps 100 --seq 512 --batch 8 [--devices 8 --mesh 2x4] \
       [--ckpt-dir /tmp/ckpt] [--comm-mode hybrid]
 
-``--devices N`` forces N host platform devices (set before jax import, so
-this module parses argv at import time — launcher-only pattern; library code
-never touches XLA_FLAGS).
+``main(argv)`` parses its own arguments, so the launcher can also be driven
+in-process (``chip_smoke.py`` does). ``--devices N`` runs on N fake CPU
+devices; it has to be given before the process has touched a JAX backend.
 """
 import argparse
-import os
-import sys
+import logging
+import time
+
+import jax
+
+from repro.configs import RunConfig, ShapeConfig, get_config, reduced
+from repro.data.pipeline import SyntheticLM
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_mesh
+from repro.runtime.trainer import Trainer, TrainerConfig
 
 
-def _parse():
+def _parse(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="phi3-medium-14b")
     ap.add_argument("--reduced", action="store_true",
@@ -21,7 +29,8 @@ def _parse():
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--seq", type=int, default=512)
     ap.add_argument("--batch", type=int, default=8)
-    ap.add_argument("--devices", type=int, default=0)
+    ap.add_argument("--devices", type=int, default=0,
+                    help="run on this many fake CPU devices (no chip)")
     ap.add_argument("--mesh", default="", help="e.g. 2x4 => data=2,model=4")
     ap.add_argument("--comm-mode", default="hybrid")
     ap.add_argument("--no-local-agg", action="store_true")
@@ -35,7 +44,7 @@ def _parse():
     ap.add_argument("--embed-impl", default="jnp",
                     choices=("jnp", "pallas"),
                     help="embedding gather/scatter kernels (pallas = TPU "
-                    "Pallas, interpret-mode off-TPU)")
+                    "Pallas, interpret mode on the CPU)")
     ap.add_argument("--zipf-a", type=float, default=1.3,
                     help="skew of the synthetic token distribution")
     ap.add_argument("--plan-zipf", action="store_true",
@@ -121,31 +130,24 @@ def _parse():
     ap.add_argument("--remat", default="block")
     ap.add_argument("--attention", default="naive")
     ap.add_argument("--seed", type=int, default=0)
-    return ap.parse_args()
+    return ap.parse_args(argv)
 
 
-ARGS = _parse()
-if ARGS.devices:
-    os.environ["XLA_FLAGS"] = (
-        f"--xla_force_host_platform_device_count={ARGS.devices} "
-        + os.environ.get("XLA_FLAGS", ""))
-
-import logging  # noqa: E402
-import jax  # noqa: E402
-
-from repro import compat  # noqa: E402
-from repro.configs import RunConfig, ShapeConfig, get_config, reduced  # noqa: E402
-from repro.data.pipeline import SyntheticLM  # noqa: E402
-from repro.launch.mesh import make_mesh  # noqa: E402
-from repro.runtime.trainer import Trainer, TrainerConfig  # noqa: E402
-
-
-def main():
+def main(argv=None):
+    """Parse ``argv`` (default ``sys.argv[1:]``), train, and return
+    ``(trainer, history)``: one dict per step with its ``loss`` and
+    ``wall_s``, the host wall time from the previous step's end to this
+    step's state being ready (the first step's includes compilation)."""
+    args = _parse(argv)
+    if args.devices:
+        jax.config.update("jax_platforms", "cpu")
+        jax.config.update("jax_num_cpu_devices", args.devices)
+    enable_compile_cache()
     logging.basicConfig(level=logging.INFO,
                         format="%(asctime)s %(name)s %(message)s")
-    print(f"jax {jax.__version__}  devices={jax.device_count()}  "
-          f"explicit_sharding={compat.has_explicit_sharding()}")
-    args = ARGS
+    dev = jax.devices()[0]
+    print(f"jax {jax.__version__}  platform={dev.platform}  "
+          f"kind={dev.device_kind}  devices={jax.device_count()}")
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduced(cfg)
@@ -198,10 +200,16 @@ def main():
     trainer = Trainer(cfg, shape, run_cfg, tcfg, ds, mesh=mesh)
     trainer.maybe_restore()
 
-    import time
-    t0 = time.time()
+    history = []
+    t0 = last = time.perf_counter()
 
     def on_metrics(step, m):
+        nonlocal last
+        jax.block_until_ready(trainer.state)
+        now = time.perf_counter()
+        history.append({"step": step, "loss": m.get("loss", float("nan")),
+                        "wall_s": now - last})
+        last = now
         if step % args.log_every == 0:
             extra = ""
             if "observed_alpha" in m:
@@ -230,9 +238,10 @@ def main():
                   f"gnorm {m.get('grad_norm', float('nan')):.3f}{extra}")
 
     trainer.run(on_metrics=on_metrics)
-    dt = time.time() - t0
-    print(f"done: {args.steps} steps in {dt:.1f}s "
-          f"({args.steps * shape.tokens / dt:.0f} tok/s avg)")
+    dt = time.perf_counter() - t0
+    print(f"done: {len(history)} steps in {dt:.1f}s "
+          f"({len(history) * shape.tokens / dt:.0f} tok/s avg)")
+    return trainer, history
 
 
 if __name__ == "__main__":

@@ -44,6 +44,7 @@ Responsibilities beyond the jitted step:
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import logging
 import math
@@ -72,7 +73,7 @@ from repro.models.model import build_model
 from repro.optim.optimizer import (fuse_state, is_fused, make_optimizer,
                                    unfuse_state)
 from repro.runtime.monitor import StepMonitor
-from repro.utils.roofline import HW
+from repro.utils.roofline import device_hw
 
 log = logging.getLogger("repro.trainer")
 
@@ -192,7 +193,7 @@ class Trainer:
         bp = self.plan.bucket_plan
         if bp is not None and not getattr(self.plan, "fused_apply", False):
             total += 2 * bp.wire_bytes
-        hw = bp.hw if bp is not None and bp.hw is not None else HW
+        hw = bp.hw if bp is not None and bp.hw is not None else device_hw()
         return total / hw.hbm_bw
 
     def _canonical_state(self):
@@ -575,7 +576,12 @@ class Trainer:
             batch = self._heartbeat_batch(self.dataset.batch(self.step))
             self.monitor.start()
             try:
-                self.state, metrics = self.train_step(self.state, batch)
+                # the live mesh is the context of every step: after a remesh
+                # it differs from whatever mesh the caller entered, and the
+                # step's shard_maps must trace against the mesh they name
+                with (compat.use_mesh(self.mesh) if self.mesh is not None
+                      else contextlib.nullcontext()):
+                    self.state, metrics = self.train_step(self.state, batch)
                 if (self.step + 1) % self.tcfg.metrics_host_every == 0:
                     metrics = {k: float(v) for k, v in metrics.items()
                                if getattr(v, "ndim", 0) == 0}

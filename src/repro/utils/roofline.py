@@ -1,6 +1,8 @@
 """Roofline math for the dry-run analysis (§Roofline).
 
-Hardware model: TPU v5e-like chip.
+Hardware model: one table entry per chip, keyed by JAX's ``device_kind``
+(``CHIPS``). ``device_hw()`` picks the entry for the chip the program runs
+on; planning off the chip (CPU tests, the dry-run) targets the v5e entry:
   peak bf16 compute : 197 TFLOP/s per chip
   HBM bandwidth     : 819 GB/s per chip
   ICI link bandwidth: ~50 GB/s per link (we use per-chip aggregate = 1 link
@@ -45,7 +47,33 @@ class Hardware:
         return self.inter_bw is not None and self.inter_latency is not None
 
 
-HW = Hardware()
+# Per-chip peaks keyed by ``jax.Device.device_kind``. TPU v5e ("TPU v5
+# lite"): Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16, 16 GB
+# HBM at 819 GB/s. The ICI and latency terms are the conservative planner
+# constants above, not published figures.
+CHIPS = {"TPU v5 lite": Hardware(name="tpu-v5e")}
+
+# the named target when planning off the chip
+HW = CHIPS["TPU v5 lite"]
+
+
+def device_hw() -> Hardware:
+    """The hardware model of the chip JAX runs on.
+
+    On a TPU backend, the ``CHIPS`` entry for the device's ``device_kind``:
+    a kind with no entry raises instead of pricing against another chip's
+    peaks. On any other backend (CPU planning and tests), the v5e target.
+    """
+    import jax
+    if jax.default_backend() != "tpu":
+        return HW
+    kind = jax.devices()[0].device_kind
+    if kind not in CHIPS:
+        raise KeyError(
+            f"no hardware model for TPU device_kind {kind!r}: add its "
+            f"published peaks to utils/roofline.CHIPS (known: "
+            f"{sorted(CHIPS)})")
+    return CHIPS[kind]
 
 # TPU vector-lane width: Pallas blocks tile the last dim in multiples of this
 LANE = 128

@@ -50,6 +50,9 @@ def distributed_run(code: str, devices: int = 8, timeout: int = 300) -> dict:
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     env.pop("XLA_FLAGS", None)
+    # fake devices are CPU devices: the child never reaches for a chip the
+    # parent may hold
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run(
         [sys.executable, "-c", prelude + textwrap.dedent(code)],
         capture_output=True, text=True, timeout=timeout, env=env)

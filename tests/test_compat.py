@@ -1,6 +1,5 @@
-"""repro.compat: mesh construction on the current JAX, capability probes,
-and the fallback paths exercised by monkeypatching the probes — so both API
-generations are covered no matter which JAX is installed."""
+"""repro.compat: mesh construction and the installed-JAX report on the one
+supported JAX (0.9.0), and the gate that keeps its spellings in compat."""
 import subprocess
 import sys
 
@@ -10,11 +9,11 @@ import numpy as np
 import pytest
 
 from repro import compat
-from repro.compat import shardmesh, version
+from repro.compat import version
 
 
 # ---------------------------------------------------------------------------
-# capability probes
+# installed-JAX report
 # ---------------------------------------------------------------------------
 
 def test_version_tuple_parses_current_jax():
@@ -34,12 +33,13 @@ def test_version_tuple_strips_dev_suffixes(monkeypatch):
 
 
 def test_probes_match_installed_jax():
-    assert version.has_axis_types() == hasattr(jax.sharding, "AxisType")
-    assert version.has_set_mesh() == hasattr(jax, "set_mesh")
-    assert version.has_top_level_shard_map() == hasattr(jax, "shard_map")
+    # the spellings compat re-exports exist on the installed JAX
+    assert compat.AxisType is jax.sharding.AxisType
+    assert hasattr(jax, "set_mesh") and hasattr(jax, "shard_map")
     caps = compat.capabilities()
     assert caps["jax_version"] == jax.__version__
-    assert caps["explicit_sharding"] == compat.has_explicit_sharding()
+    assert caps["min_supported"] == list(compat.MIN_SUPPORTED)
+    assert caps["supported"] == compat.supported()
 
 
 # ---------------------------------------------------------------------------
@@ -77,57 +77,7 @@ def test_shard_map_runs_on_current_jax():
 
 
 # ---------------------------------------------------------------------------
-# fallback paths, forced via the probes
-# ---------------------------------------------------------------------------
-
-def test_make_mesh_fallback_without_axis_types(monkeypatch):
-    monkeypatch.setattr(version, "has_axis_types", lambda: False)
-    mesh = compat.make_mesh((1,), ("data",))
-    assert mesh.shape["data"] == 1
-    # Auto axis_types are accepted and dropped...
-    mesh = compat.make_mesh((1,), ("data",),
-                            axis_types=(shardmesh.AxisType.Auto,))
-    assert mesh.axis_names == ("data",)
-    # ...but Explicit must fail loudly, never silently downgrade
-    with pytest.raises(NotImplementedError):
-        compat.make_mesh((1,), ("data",),
-                         axis_types=(shardmesh.AxisType.Explicit,))
-
-
-def test_make_mesh_fallback_without_jax_make_mesh(monkeypatch):
-    monkeypatch.setattr(version, "has_axis_types", lambda: False)
-    monkeypatch.delattr(jax, "make_mesh")
-    mesh = compat.make_mesh((1,), ("data",))
-    assert mesh.axis_names == ("data",) and mesh.shape["data"] == 1
-
-
-def test_use_mesh_fallback_is_noop(monkeypatch):
-    monkeypatch.setattr(version, "has_set_mesh", lambda: False)
-    monkeypatch.setattr(version, "has_use_mesh", lambda: False)
-    mesh = compat.make_mesh((1,), ("data",))
-    with compat.use_mesh(mesh) as m:
-        assert m is mesh
-
-
-def test_shard_map_fallback_via_experimental(monkeypatch):
-    monkeypatch.setattr(version, "has_top_level_shard_map", lambda: False)
-    mesh = compat.make_mesh((1,), ("data",))
-    fn = compat.shard_map(lambda x: x + 1, mesh=mesh,
-                          in_specs=compat.P("data"),
-                          out_specs=compat.P("data"), check_vma=False)
-    np.testing.assert_array_equal(np.asarray(fn(jnp.zeros(2))), np.ones(2))
-
-
-def test_explicit_sharding_probe_composition(monkeypatch):
-    monkeypatch.setattr(version, "has_axis_types", lambda: False)
-    assert not version.has_explicit_sharding()
-    monkeypatch.setattr(version, "has_axis_types", lambda: True)
-    monkeypatch.setattr(version, "has_set_mesh", lambda: True)
-    assert version.has_explicit_sharding()
-
-
-# ---------------------------------------------------------------------------
-# cost_analysis normalization (list-of-dicts on 0.4.x, dict on newer)
+# cost_analysis normalization (a dict, empty where XLA reports none)
 # ---------------------------------------------------------------------------
 
 def test_cost_analysis_normalized_shapes():
@@ -138,9 +88,7 @@ def test_cost_analysis_normalized_shapes():
         def cost_analysis(self):
             return self._ret
 
-    assert compat.cost_analysis(_C([{"flops": 2.0}])) == {"flops": 2.0}
     assert compat.cost_analysis(_C({"flops": 3.0})) == {"flops": 3.0}
-    assert compat.cost_analysis(_C([])) == {}
     assert compat.cost_analysis(_C(None)) == {}
     compiled = jax.jit(lambda x: x @ x).lower(
         jax.ShapeDtypeStruct((8, 8), jnp.float32)).compile()

@@ -3,7 +3,7 @@
 
     PYTHONPATH=src python tools/check_env.py [--json]
 
-Prints the JAX version, device count, repro.compat capability probes, and
+Prints the JAX version against the one supported JAX, device count, and
 optional-dependency presence, then a PASS/WARN verdict — so a broken
 environment shows up as one readable line instead of 16 cryptic test
 failures. tests/test_compat.py::test_check_env_smoke runs this on every
@@ -189,15 +189,15 @@ def _host_topology() -> dict:
 
 def _probe_pallas() -> dict:
     """Can RunConfig.embed_impl='pallas' serve the sparse hot path here?
-    Off-TPU the kernels run in interpret mode — available but slow."""
-    import jax
+    On the CPU backend the kernels run in interpret mode — available but
+    slow."""
     try:
         import numpy as np
         from repro.kernels import ops
         out = ops.embed_gather(np.zeros((8, 4), np.float32),
                                np.zeros((4,), np.int32))
         return {"available": bool(np.asarray(out).shape == (4, 4)),
-                "interpret_mode": jax.default_backend() != "tpu"}
+                "interpret_mode": ops.interpret_mode()}
     except Exception as e:  # pallas import / lowering failure
         return {"available": False, "error": f"{type(e).__name__}: {e}"}
 
@@ -212,18 +212,14 @@ def main() -> int:
         print(json.dumps(report))
         return 0 if report["ok"] else 1
 
-    from repro.compat import MIN_SUPPORTED
     j = report["jax"]
     print(f"python {report['python']}  jax {j['jax_version']}  "
           f"jaxlib {report['jaxlib']}  backend={report['backend']}  "
           f"devices={report['device_count']} "
           f"(local={report['local_device_count']}, "
           f"hosts={report['process_count']})")
-    print(f"compat: explicit_sharding={j['explicit_sharding']}  "
-          f"axis_types={j['axis_types']}  set_mesh={j['set_mesh']}  "
-          f"top_level_shard_map={j['top_level_shard_map']}  "
-          f"supported(>= {'.'.join(map(str, MIN_SUPPORTED))})"
-          f"={j['supported']}")
+    print(f"compat: supported(>= "
+          f"{'.'.join(map(str, j['min_supported']))})={j['supported']}")
     missing = [k for k, v in report["optional_deps"].items() if not v]
     present = [k for k, v in report["optional_deps"].items() if v]
     print("optional deps: "
@@ -232,8 +228,8 @@ def main() -> int:
                          for k in missing]))
     pal = report["embed_impl_pallas"]
     if pal.get("available"):
-        mode = "interpret mode (off-TPU)" if pal.get("interpret_mode") \
-            else "compiled (TPU)"
+        mode = "interpret mode (CPU)" if pal.get("interpret_mode") \
+            else "compiled (Mosaic)"
         print(f"embed_impl=pallas: available, {mode}")
     else:
         print("embed_impl=pallas: UNAVAILABLE "
@@ -285,7 +281,7 @@ def main() -> int:
           f"{'on' if sv['detokenize_thread'] else 'off'}; "
           f"{'/'.join(sv['toy_fallback_families'])} -> ToyServer")
     print("PASS" if report["ok"] else
-          "WARN: JAX older than the supported range — tier-1 results are "
+          "WARN: JAX older than the supported version — tier-1 results are "
           "not meaningful")
     return 0 if report["ok"] else 1
 
