@@ -65,6 +65,7 @@ from repro.compat import P, shard_map
 from repro.core import cost_model, embedding
 from repro.core.plan import ParamPlan, Plan, plan_leaves
 from repro.core.runtime import manual_region
+from repro.utils.hlo import EXCHANGE, FORWARD
 from repro.utils.roofline import HW, device_hw
 
 
@@ -347,6 +348,7 @@ def _two_level_psum(buf, batch_axes: tuple, local: int):
     return out[:n] if pad else out
 
 
+@jax.named_scope(EXCHANGE)
 def _exchange_bucket(b: Bucket, gparts: list, scale: float, bp: BucketPlan,
                      census: bool, pin=None):
     """The fused exchange for ONE bucket: flatten → 1/N scale → census →
@@ -474,6 +476,10 @@ def make_bucketed_value_and_grad(model, rt, plan: Plan) -> Callable:
     taps = [t for t, _ in taps_and_sizes]
     token_sizes = [s for _, s in taps_and_sizes]
 
+    def loss_fn(params, batch):
+        with jax.named_scope(FORWARD):
+            return model.loss_fn(params, batch)
+
     def loss_tapped(params, tokens, batch):
         # taps must wrap the parameters *inside* the differentiated
         # function — wrapping before value_and_grad would leave the tap
@@ -483,8 +489,7 @@ def make_bucketed_value_and_grad(model, rt, plan: Plan) -> Callable:
             tapped = taps[k](tuple(pleaves[i] for i in b.idx), tokens[k])
             for j, i in enumerate(b.idx):
                 pleaves[i] = tapped[j]
-        return model.loss_fn(
-            jax.tree_util.tree_unflatten(ptree, pleaves), batch)
+        return loss_fn(jax.tree_util.tree_unflatten(ptree, pleaves), batch)
 
     def body(params, batch):
         batch = dict(batch)
@@ -509,7 +514,7 @@ def make_bucketed_value_and_grad(model, rt, plan: Plan) -> Callable:
         else:
             with manual_region():
                 (loss, metrics), grads = jax.value_and_grad(
-                    model.loss_fn, has_aux=True)(params, batch)
+                    loss_fn, has_aux=True)(params, batch)
             metrics = dict(metrics)
             gleaves, gtree = jax.tree_util.tree_flatten(grads)
             out = list(gleaves)
@@ -539,25 +544,27 @@ def make_bucketed_value_and_grad(model, rt, plan: Plan) -> Callable:
                 uids = metrics.pop(f"{name}_uids")
                 gleaves[i] = embedding.deferred_push(
                     gleaves[i], uids, ectx, pin=pin)
-        for i, g in enumerate(gleaves):
-            if i in bucketed:
-                continue
-            # sparse push already exchanged inside the lookup's VJP
-            # (replica-summed); only the loss-mean 1/N remains
-            g32 = g.astype(jnp.float32) * scale
-            if grad_census and i in sparse_tables and g32.ndim >= 2:
-                # sparse row-buffer magnitude census: |g|inf and rms over
-                # the rows the push actually touched (zero rows excluded —
-                # the replica-sum inflates max and rms by the same factor,
-                # so the peak-to-rms pin ratio is unaffected)
-                name = sparse_tables[i]
-                rows = jnp.any(g32 != 0.0, axis=tuple(range(1, g32.ndim)))
-                width = g32.size // g32.shape[0]
-                nnz = jnp.maximum(jnp.sum(rows.astype(jnp.float32)), 1.0)
-                metrics[f"{name}_gmax"] = jnp.max(jnp.abs(g32))
-                metrics[f"{name}_grms"] = jnp.sqrt(
-                    jnp.sum(jnp.square(g32)) / (nnz * width))
-            out[i] = g32.astype(g.dtype)
+        with jax.named_scope(EXCHANGE):
+            for i, g in enumerate(gleaves):
+                if i in bucketed:
+                    continue
+                # sparse push already exchanged inside the lookup's VJP
+                # (replica-summed); only the loss-mean 1/N remains
+                g32 = g.astype(jnp.float32) * scale
+                if grad_census and i in sparse_tables and g32.ndim >= 2:
+                    # sparse row-buffer magnitude census: |g|inf and rms
+                    # over the rows the push actually touched (zero rows
+                    # excluded — the replica-sum inflates max and rms by
+                    # the same factor, so the peak-to-rms pin ratio is
+                    # unaffected)
+                    name = sparse_tables[i]
+                    rows = jnp.any(g32 != 0.0, axis=tuple(range(1, g32.ndim)))
+                    width = g32.size // g32.shape[0]
+                    nnz = jnp.maximum(jnp.sum(rows.astype(jnp.float32)), 1.0)
+                    metrics[f"{name}_gmax"] = jnp.max(jnp.abs(g32))
+                    metrics[f"{name}_grms"] = jnp.sqrt(
+                        jnp.sum(jnp.square(g32)) / (nnz * width))
+                out[i] = g32.astype(g.dtype)
         grads_out = jax.tree_util.tree_unflatten(gtree, out)
 
         if hb is not None:
@@ -576,20 +583,21 @@ def make_bucketed_value_and_grad(model, rt, plan: Plan) -> Callable:
         # rank>=1 metric leaves (none today) pmean individually — returning
         # them raw through out_specs=P() would silently pass one device's
         # local value off as the global metric
-        mleaves, mtree = jax.tree_util.tree_flatten(metrics)
-        scalar_pos = [j for j, x in enumerate(mleaves)
-                      if jnp.ndim(x) == 0]
-        vec = jnp.stack([loss.astype(jnp.float32)] +
-                        [mleaves[j].astype(jnp.float32)
-                         for j in scalar_pos])
-        vec = jax.lax.psum(vec, bp.batch_axes) * scale
-        loss_out = vec[0]
-        for k, j in enumerate(scalar_pos):
-            mleaves[j] = vec[1 + k]
-        for j, x in enumerate(mleaves):
-            if jnp.ndim(x) > 0:
-                mleaves[j] = jax.lax.psum(
-                    x.astype(jnp.float32), bp.batch_axes) * scale
+        with jax.named_scope(EXCHANGE):
+            mleaves, mtree = jax.tree_util.tree_flatten(metrics)
+            scalar_pos = [j for j, x in enumerate(mleaves)
+                          if jnp.ndim(x) == 0]
+            vec = jnp.stack([loss.astype(jnp.float32)] +
+                            [mleaves[j].astype(jnp.float32)
+                             for j in scalar_pos])
+            vec = jax.lax.psum(vec, bp.batch_axes) * scale
+            loss_out = vec[0]
+            for k, j in enumerate(scalar_pos):
+                mleaves[j] = vec[1 + k]
+            for j, x in enumerate(mleaves):
+                if jnp.ndim(x) > 0:
+                    mleaves[j] = jax.lax.psum(
+                        x.astype(jnp.float32), bp.batch_axes) * scale
         metrics_out = jax.tree_util.tree_unflatten(mtree, mleaves)
         if want_bufs:
             # post-psum buffers are replica-identical; they leave the

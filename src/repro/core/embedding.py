@@ -27,6 +27,7 @@ never drops; overflow is counted in the metrics.
 """
 from __future__ import annotations
 
+import contextlib
 from dataclasses import dataclass, replace as _dc_replace
 from functools import partial
 from typing import Any, Optional
@@ -36,6 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.compat import Mesh, P, shard_map
+from repro.utils.hlo import EXCHANGE
 
 
 @dataclass(frozen=True)
@@ -196,7 +198,8 @@ def _fwd_local(table_shard, ids_loc, ctx: EmbedCtx, capacity: int):
         ctx.method not in ("dense", "allreduce")
     if in_shard_map and ctx.batch_axes:
         if ctx.census:
-            uniq = jax.lax.psum(uniq, ctx.batch_axes) / ctx.replicas
+            with jax.named_scope(EXCHANGE):
+                uniq = jax.lax.psum(uniq, ctx.batch_axes) / ctx.replicas
         else:
             # census off (serve path): drop the measurement rather than
             # declare a device-varying scalar replicated (out_specs P())
@@ -205,9 +208,10 @@ def _fwd_local(table_shard, ids_loc, ctx: EmbedCtx, capacity: int):
     if ctx.model_shards > 1:
         m = jax.lax.axis_index(ctx.model_axis)
         rows = _gather_rows(table_shard, uids - m * vs, ctx)
-        rows = rows.astype(ctx.wire_dtype)
-        rows = jax.lax.psum(rows, ctx.model_axis)     # pull: ~2αb over model
-        rows = rows.astype(table_shard.dtype)
+        with jax.named_scope(EXCHANGE):
+            rows = rows.astype(ctx.wire_dtype)
+            rows = jax.lax.psum(rows, ctx.model_axis)  # pull: ~2αb over model
+            rows = rows.astype(table_shard.dtype)
     else:
         rows = _gather_rows(table_shard, uids, ctx)
     rows_pad = jnp.concatenate([rows, jnp.zeros_like(rows[:1])], axis=0)
@@ -236,35 +240,44 @@ def _bwd_local(uids_row, inv_loc, d_out_loc, vs_shard, ctx: EmbedCtx):
         # paper's MPI baseline: all-gather (ids, rows) over every replica.
         # Gathered ids duplicate across replicas -> jnp scatter-add (the
         # overwrite-style Pallas kernel needs unique ids), via local_agg=False
-        if ctx.batch_axes:
-            uids_all = jax.lax.all_gather(uids, ctx.batch_axes,
-                                          tiled=False).reshape(-1)
-            rows_all = jax.lax.all_gather(d_rows, ctx.batch_axes,
-                                          tiled=False).reshape(-1, d_rows.shape[-1])
-        else:
-            uids_all, rows_all = uids, d_rows
-        return _scatter_rows(uids_all, rows_all, vs_shard,
-                             _dc_replace(ctx, local_agg=False))
+        with _exchange_scope(ctx):
+            uids_all, rows_all = _gather_pushed(uids, d_rows, ctx)
+            return _scatter_rows(uids_all, rows_all, vs_shard,
+                                 _dc_replace(ctx, local_agg=False))
 
     m = jax.lax.axis_index(ctx.model_axis) if ctx.model_shards > 1 else 0
     if ctx.method == "ps_gather":
         # sparse all-gather over replicas, owner-local scatter (D·αb)
-        if ctx.batch_axes:
-            uids_all = jax.lax.all_gather(uids, ctx.batch_axes,
-                                          tiled=False).reshape(-1)
-            rows_all = jax.lax.all_gather(d_rows, ctx.batch_axes,
-                                          tiled=False).reshape(-1, d_rows.shape[-1])
-        else:
-            uids_all, rows_all = uids, d_rows
-        return _scatter_rows(uids_all - m * vs_shard, rows_all, vs_shard,
-                             _dc_replace(ctx, local_agg=False))
+        with _exchange_scope(ctx):
+            uids_all, rows_all = _gather_pushed(uids, d_rows, ctx)
+            return _scatter_rows(uids_all - m * vs_shard, rows_all, vs_shard,
+                                 _dc_replace(ctx, local_agg=False))
 
     # "ps": owner-local scatter-add + dense shard psum over replicas (2b/M)
     d = _scatter_rows(uids - m * vs_shard, d_rows, vs_shard, ctx)
     if ctx.batch_axes:
-        d = jax.lax.psum(d.astype(ctx.wire_dtype), ctx.batch_axes
-                         ).astype(jnp.float32)
+        with jax.named_scope(EXCHANGE):
+            d = jax.lax.psum(d.astype(ctx.wire_dtype), ctx.batch_axes
+                             ).astype(jnp.float32)
     return d
+
+
+def _exchange_scope(ctx: EmbedCtx):
+    """The ``exchange`` named scope when the push crosses replicas; none
+    when it stays on one device (its scatter is then the backward's)."""
+    return jax.named_scope(EXCHANGE) if ctx.batch_axes \
+        else contextlib.nullcontext()
+
+
+def _gather_pushed(uids, d_rows, ctx: EmbedCtx):
+    """All-gather the pushed (ids, rows) over the replicas, flattened."""
+    if not ctx.batch_axes:
+        return uids, d_rows
+    uids_all = jax.lax.all_gather(uids, ctx.batch_axes,
+                                  tiled=False).reshape(-1)
+    rows_all = jax.lax.all_gather(d_rows, ctx.batch_axes,
+                                  tiled=False).reshape(-1, d_rows.shape[-1])
+    return uids_all, rows_all
 
 
 def pin_after(x, dep):
@@ -317,6 +330,7 @@ def overlap_gate(table, activation):
     return _gate(table, activation)
 
 
+@jax.named_scope(EXCHANGE)
 def deferred_push(g_local, uids, ctx: EmbedCtx, pin=None):
     """Post-backward gatherv push for a deferred table (``EmbedCtx.
     defer_push``): re-extract the deduped wire rows from the locally-

@@ -44,6 +44,7 @@ from repro.models.layers import ParamSpec
 from repro.models.model import Model, build_model
 from repro.optim.optimizer import (Optimizer, TrainState, fuse_state,
                                    is_fused, make_optimizer, unfuse_state)
+from repro.utils.hlo import FORWARD, OPTIMIZER
 from repro.utils.tree import named_leaves, path_name as tree_path_name
 
 
@@ -442,8 +443,9 @@ def make_train_step(model: Model, optimizer: Optimizer, rt: Runtime,
                 metrics = dict(metrics)
                 new_stale, grads, metrics = stale_rule(
                     getattr(state, "stale", None), grads, metrics)
-                new_state, opt_metrics = optimizer.update_fused(
-                    state, grads, bufs, bp)
+                with jax.named_scope(OPTIMIZER):
+                    new_state, opt_metrics = optimizer.update_fused(
+                        state, grads, bufs, bp)
                 new_state = new_state._replace(stale=new_stale)
                 metrics.update(opt_metrics)
                 metrics["loss"] = loss
@@ -451,9 +453,13 @@ def make_train_step(model: Model, optimizer: Optimizer, rt: Runtime,
 
             return train_step_fused
     else:
+        def loss_fn(params, batch):
+            with jax.named_scope(FORWARD):
+                return model.loss_fn(params, batch)
+
         def value_and_grad(params, batch):
             out, grads = jax.value_and_grad(
-                model.loss_fn, has_aux=True)(params, batch)
+                loss_fn, has_aux=True)(params, batch)
             # OPSW: dense grads ride collectives at each parameter's planned
             # wire dtype (profiled per-bucket magnitude census can pin
             # outlier-prone parameters to f32). In global semantics the
@@ -478,7 +484,8 @@ def make_train_step(model: Model, optimizer: Optimizer, rt: Runtime,
         metrics = dict(metrics)
         new_stale, grads, metrics = stale_rule(
             getattr(state, "stale", None), grads, metrics)
-        new_state, opt_metrics = optimizer.update(state, grads)
+        with jax.named_scope(OPTIMIZER):
+            new_state, opt_metrics = optimizer.update(state, grads)
         new_state = new_state._replace(stale=new_stale)
         metrics.update(opt_metrics)
         metrics["loss"] = loss

@@ -201,6 +201,7 @@ def main(argv=None):
     trainer.maybe_restore()
 
     history = []
+    first_compiles = []
     t0 = last = time.perf_counter()
 
     def on_metrics(step, m):
@@ -210,6 +211,8 @@ def main(argv=None):
         history.append({"step": step, "loss": m.get("loss", float("nan")),
                         "wall_s": now - last})
         last = now
+        if not first_compiles:
+            first_compiles.append(m["compiles"])
         if step % args.log_every == 0:
             extra = ""
             if "observed_alpha" in m:
@@ -227,8 +230,8 @@ def main(argv=None):
                 extra += f"  stale {'on' if m['stale_mode'] else 'off'}"
             if m.get("ckpt_retries"):
                 extra += f"  ckpt-retries {int(m['ckpt_retries'])}"
-            if "apply_seconds" in m:
-                extra += f"  apply {m['apply_seconds'] * 1e6:.0f}us"
+            if m["compiles"] > first_compiles[0]:
+                extra += f"  recompiles {m['compiles'] - first_compiles[0]}"
             if m.get("n_overlapped_sparse"):
                 extra += f"  ovl-sparse {int(m['n_overlapped_sparse'])}"
             if "ckpt_error" in m:

@@ -38,6 +38,30 @@ reports the observed sparsity α (from the SparsityProfile EMA) and every
 plan hot-swap, and both show up in the per-step stats dict — as does any
 error the async checkpointer hit in the background (``note_ckpt_error``),
 so a failing checkpoint path is visible *now*, not on the next ``wait()``.
+
+Tracing: the monitor is also where the program names its own time.
+
+* Host spans (``span``, ``step_span``): each opens a
+  ``jax.profiler.TraceAnnotation`` under its name, so that under a profiler
+  it sits on the device ops' clock, and adds its ``perf_counter`` duration
+  to in-memory records that stay on with the profiler off: per name a sum,
+  a count and a max (``span_stats``), and per step the spans of the last
+  ``SPAN_STEPS`` steps (``step_spans``). The trainer opens ``train.step``
+  (a ``StepTraceAnnotation`` with ``step_num``) around each iteration and,
+  inside it, ``train.input``, ``train.dispatch``, ``train.host_sync`` and
+  ``train.callback``; ``train.rebuild`` around a replan, remesh, readmit,
+  stale flip or restore-adopt, and ``train.checkpoint`` around the periodic
+  and final saves.
+* Device phases: the step's code opens the ``jax.named_scope``s that
+  ``repro.utils.hlo`` names (``FORWARD``, ``OPTIMIZER``, ``EXCHANGE``), and
+  ``utils.hlo.step_phases`` reads them, the backward's included, back from
+  the compiled step.
+* Compiles: one ``jax.monitoring`` listener per process, registered when
+  the first monitor is built, counts ``/jax/core/compile/backend_compile_
+  duration``, which JAX records once per program it compiles or fetches
+  from the persistent compile cache (a cache hit records it too, beside
+  ``/jax/compilation_cache/cache_hits``). ``StepMonitor.compiles`` reads
+  the count and the step stats carry it.
 """
 from __future__ import annotations
 
@@ -45,6 +69,51 @@ import collections
 import time
 from dataclasses import dataclass, field
 from typing import Optional
+
+import jax
+
+# host spans
+STEP, INPUT, DISPATCH, HOST_SYNC, CALLBACK, REBUILD, CHECKPOINT = (
+    "train.step", "train.input", "train.dispatch", "train.host_sync",
+    "train.callback", "train.rebuild", "train.checkpoint")
+SPAN_STEPS = 64          # steps whose per-step spans are kept
+
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_compiles = 0
+_listening = False
+
+
+def _count_compile(event: str, secs: float, **kwargs) -> None:
+    global _compiles
+    if event == _COMPILE_EVENT:
+        _compiles += 1
+
+
+def _listen_for_compiles() -> None:
+    global _listening
+    if not _listening:
+        _listening = True
+        jax.monitoring.register_event_duration_secs_listener(_count_compile)
+
+
+class _Span:
+    """One host span: a profiler annotation and a ``perf_counter`` reading
+    the monitor records on exit."""
+    __slots__ = ("_mon", "_name", "_ann", "_t0")
+
+    def __init__(self, mon: "StepMonitor", name: str, ann):
+        self._mon, self._name, self._ann = mon, name, ann
+
+    def __enter__(self):
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        dt = time.perf_counter() - self._t0
+        self._ann.__exit__(*exc)
+        self._mon._record(self._name, dt)
+        return False
 
 
 @dataclass
@@ -83,14 +152,66 @@ class StepMonitor:
     ckpt_error: Optional[str] = None         # background checkpoint failure
     exchange: Optional[dict] = None          # bucketed-exchange accounting
                                              # (core/buckets.py stats)
-    apply_seconds: Optional[float] = None    # analytic optimizer-apply cost
-                                             # (state bytes / HBM bandwidth,
-                                             # fused-apply aware)
     overflow: Optional[dict] = None          # per-table embed_dropped EMA
                                              # (rows silently zeroed / step)
+    _spans: dict = field(default_factory=dict)  # name -> [sum_s, count, max_s]
+    _recent: list = field(default_factory=lambda: [
+        [-1, {}] for _ in range(SPAN_STEPS)])    # [step, {name: secs}] ring
+    _open: Optional[dict] = None             # the open step's span record
+
+    def __post_init__(self):
+        _listen_for_compiles()
 
     def start(self):
         self._last = time.perf_counter()
+
+    def span(self, name: str) -> _Span:
+        """A host span called ``name`` (a context manager): see the module
+        docstring. It never waits on the device."""
+        return _Span(self, name, jax.profiler.TraceAnnotation(name))
+
+    def step_span(self, step: int) -> _Span:
+        """The span of training step ``step``: a ``StepTraceAnnotation``
+        ``train.step`` with ``step_num=step``; the step's timing sample
+        starts here, and spans opened inside it are kept as its record."""
+        slot = self._recent[step % SPAN_STEPS]
+        slot[0] = step
+        slot[1].clear()
+        self._open = slot[1]
+        self.start()
+        return _Span(self, STEP, jax.profiler.StepTraceAnnotation(
+            STEP, step_num=step))
+
+    def _record(self, name: str, dt: float) -> None:
+        tot = self._spans.get(name)
+        if tot is None:
+            tot = self._spans[name] = [0.0, 0, 0.0]
+        tot[0] += dt
+        tot[1] += 1
+        if dt > tot[2]:
+            tot[2] = dt
+        if self._open is not None:
+            self._open[name] = self._open.get(name, 0.0) + dt
+            if name == STEP:
+                self._open = None
+
+    def span_stats(self) -> dict:
+        """``{name: {"sum_s", "count", "max_s"}}`` over every span so far."""
+        return {k: {"sum_s": v[0], "count": v[1], "max_s": v[2]}
+                for k, v in self._spans.items()}
+
+    def step_spans(self, step: int) -> Optional[dict]:
+        """``{name: seconds}`` of the spans inside step ``step`` (the
+        ``step_num`` it ran as), or None once it has left the last
+        ``SPAN_STEPS`` steps."""
+        slot = self._recent[step % SPAN_STEPS]
+        return dict(slot[1]) if slot[0] == step else None
+
+    @property
+    def compiles(self) -> int:
+        """Programs this process compiled or fetched from the compile cache
+        since the first monitor was built."""
+        return _compiles
 
     def note_alpha(self, alpha: float):
         self.observed_alpha = float(alpha)
@@ -221,13 +342,6 @@ class StepMonitor:
         wire bytes, and per-step collective launches (None = per-tensor)."""
         self.exchange = dict(stats) if stats else None
 
-    def note_apply(self, seconds: Optional[float]):
-        """Record the analytic optimizer-apply cost for the live plan —
-        total HBM traffic of the update (params/moments/EMA read+write,
-        grads read, plus the unflatten->reflatten round trip the fused
-        bucket-apply skips) over the hardware model's bandwidth."""
-        self.apply_seconds = None if seconds is None else float(seconds)
-
     def stop(self, tokens: int = 0) -> dict:
         # a cleared _last means note_recovery dropped the in-flight sample
         # (the pause spans a restore, not a training step): keep the
@@ -259,6 +373,7 @@ class StepMonitor:
             "replans": self.replans,
             "remeshes": self.remeshes,
             "regrows": self.regrows,
+            "compiles": self.compiles,
         }
         if self.heartbeats:
             stats["heartbeats"] = dict(self.heartbeats)
@@ -298,8 +413,6 @@ class StepMonitor:
             if "n_overlapped_sparse" in self.exchange:
                 stats["n_overlapped_sparse"] = \
                     self.exchange["n_overlapped_sparse"]
-        if self.apply_seconds is not None:
-            stats["apply_seconds"] = self.apply_seconds
         return stats
 
     def median(self) -> float:

@@ -46,6 +46,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import logging
 import math
 import time
@@ -72,10 +73,21 @@ from repro.launch.mesh import grow_mesh, shrink_mesh
 from repro.models.model import build_model
 from repro.optim.optimizer import (fuse_state, is_fused, make_optimizer,
                                    unfuse_state)
+from repro.runtime import monitor as spans
 from repro.runtime.monitor import StepMonitor
-from repro.utils.roofline import device_hw
 
 log = logging.getLogger("repro.trainer")
+
+
+def _spanned(name: str):
+    """Run a Trainer method inside the monitor's host span ``name``."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def method(self, *args, **kwargs):
+            with self.monitor.span(name):
+                return fn(self, *args, **kwargs)
+        return method
+    return wrap
 
 
 def _bucket_signature(plan) -> tuple:
@@ -170,31 +182,6 @@ class Trainer:
     def _note_plan_costs(self):
         self.monitor.note_exchange(
             self.plan.bucket_plan.stats() if self.plan.bucket_plan else None)
-        self.monitor.note_apply(self._apply_seconds_estimate())
-
-    def _apply_seconds_estimate(self) -> Optional[float]:
-        """Analytic optimizer-apply cost for the live plan: HBM bytes the
-        update moves over the hardware model's bandwidth. Params are read
-        and written, each f32 moment (and the EMA) is read and written,
-        gradients are read once; the per-param path under a bucket plan
-        additionally pays the unflatten->reflatten round trip over the
-        fused gradient buffers that the bucket-native apply skips."""
-        leaves = plan_leaves(self.plan.params)
-        if not leaves:
-            return None
-        itemsize = jnp.dtype(self.rt.param_dtype).itemsize
-        pbytes = sum(p.bytes for p in leaves)
-        f32b = sum(p.bytes // itemsize for p in leaves) * 4
-        n_moments = {"adamw": 2, "momentum": 1}.get(
-            self.run_cfg.optimizer, 0)
-        total = 3 * pbytes + 2 * n_moments * f32b
-        if self.run_cfg.ema_decay:
-            total += 2 * f32b
-        bp = self.plan.bucket_plan
-        if bp is not None and not getattr(self.plan, "fused_apply", False):
-            total += 2 * bp.wire_bytes
-        hw = bp.hw if bp is not None and bp.hw is not None else device_hw()
-        return total / hw.hbm_bw
 
     def _canonical_state(self):
         """The live state in the canonical per-param layout. Checkpoints,
@@ -260,6 +247,7 @@ class Trainer:
         self.monitor.note_recovery()
         log.info("restored checkpoint at step %d", self.step)
 
+    @_spanned(spans.REBUILD)
     def _adopt_saved_plan(self, saved: dict, wire_pins: Optional[dict] = None):
         """Re-analyze + rebuild the jitted step against a checkpoint's plan
         record. The saved per-table α reproduces the Table-3 method argmin,
@@ -331,6 +319,7 @@ class Trainer:
                                if m != "allreduce"])
         return census
 
+    @_spanned(spans.REBUILD)
     def remesh(self, new_mesh):
         """Elastic re-mesh: reshard live state onto a new mesh (e.g. after
         dropping a failed host slice). The rebuild derives shardings from
@@ -474,6 +463,7 @@ class Trainer:
             diff["flips"])
         return diff
 
+    @_spanned(spans.REBUILD)
     def _flip_stale(self, on: bool) -> Optional[dict]:
         """Flip the stale-eligible sparse tables to (or back from) the
         bounded-staleness push and hot-swap the jitted step. The staleness
@@ -507,6 +497,7 @@ class Trainer:
         return diff
 
     # ------------------------------------------------------------------
+    @_spanned(spans.REBUILD)
     def maybe_replan(self) -> Optional[dict]:
         """Re-run the planner on the observed census; hot-swap on change.
 
@@ -571,120 +562,141 @@ class Trainer:
 
     def run(self, on_metrics: Optional[Callable[[int, dict], None]] = None):
         tokens_per_step = self.shape_cfg.tokens
+        mon = self.monitor
         retries = 0
         while self.step < self.tcfg.total_steps:
-            batch = self._heartbeat_batch(self.dataset.batch(self.step))
-            self.monitor.start()
+            with mon.step_span(self.step):
+                with mon.span(spans.INPUT):
+                    batch = self._heartbeat_batch(
+                        self.dataset.batch(self.step))
+                try:
+                    # the live mesh is the context of every step: after a
+                    # remesh it differs from whatever mesh the caller
+                    # entered, and the step's shard_maps must trace against
+                    # the mesh they name
+                    with (compat.use_mesh(self.mesh) if self.mesh is not None
+                          else contextlib.nullcontext()), \
+                            mon.span(spans.DISPATCH):
+                        self.state, metrics = self.train_step(self.state,
+                                                              batch)
+                    if (self.step + 1) % self.tcfg.metrics_host_every == 0:
+                        with mon.span(spans.HOST_SYNC):
+                            metrics = {k: float(v) for k, v in metrics.items()
+                                       if getattr(v, "ndim", 0) == 0}
+                        # decode the heartbeat slots out of the fused
+                        # metrics psum into the attribution state (and out
+                        # of the user-visible metrics — stats carries the
+                        # EMAs)
+                        beats = {int(k[9:]): metrics.pop(k)
+                                 for k in list(metrics)
+                                 if k.startswith("heartbeat")
+                                 and k[9:].isdigit()}
+                        if beats:
+                            mon.note_heartbeats(beats)
+                        self.profile.update(metrics)
+                        # overflow is visible host-side every profiled step,
+                        # not just when (or if) the growth replan fires;
+                        # restricted to real sparse tables (the MoE router
+                        # also emits a *_dropped scalar that is not buffer
+                        # overflow)
+                        mon.note_overflow(
+                            self.profile.dropped(self.plan.table_methods))
+                    retries = 0
+                except Exception:  # failure path: restore + retry
+                    retries += 1
+                    log.exception("step %d failed (retry %d/%d)",
+                                  self.step, retries, self.tcfg.max_retries)
+                    if retries > self.tcfg.max_retries or self.ckpt is None:
+                        raise
+                    try:
+                        self.ckpt.wait()
+                    except Exception:
+                        log.exception("in-flight checkpoint also failed")
+                    if latest_step(self.tcfg.ckpt_dir) is None:
+                        # no committed checkpoint to fall back on — and the
+                        # failed call may already have consumed the donated
+                        # state buffers, so retrying on self.state would
+                        # feed the step poisoned memory. Rebuild from
+                        # scratch.
+                        log.warning("no committed checkpoint: "
+                                    "reinitializing state from seed %d at "
+                                    "step 0", self.run_cfg.seed)
+                        self.train_step, self.state, self.shardings = \
+                            build_step(self.model, self.optimizer, self.rt,
+                                       self.plan, None,
+                                       seed=self.run_cfg.seed)
+                        self.step = 0
+                        mon.note_recovery()
+                    else:
+                        self.maybe_restore()
+                    continue
+                stats = mon.stop(tokens=tokens_per_step)
+                self.step += 1
+                self._after_step(stats)
+                if on_metrics is not None:
+                    with mon.span(spans.CALLBACK):
+                        on_metrics(self.step, {**metrics, **stats})
+                elif self.step % self.tcfg.log_every == 0:
+                    log.info("step %d loss %.4f %.0f tok/s", self.step,
+                             metrics.get("loss", float("nan")),
+                             stats["tokens_per_s"])
+        if self.ckpt is not None:
+            with mon.span(spans.CHECKPOINT):
+                self.ckpt.save(self.step, self._canonical_state(),
+                               extra=self._ckpt_extra())
+                self.ckpt.wait()
+        return self.state
+
+    def _after_step(self, stats: dict) -> None:
+        """The host's work between a step and its metrics callback: the
+        replan loop, the checkpoint mirror and periodic save, and the
+        monitor's escalations. Notes what it did in ``stats``."""
+        mon = self.monitor
+        if self.tcfg.replan_every and \
+                self.step % self.tcfg.replan_every == 0:
+            self.maybe_replan()
+            # this step's stats must reflect a replan it triggered
+            stats["replans"] = mon.replans
+            if mon.observed_alpha is not None:
+                stats["observed_alpha"] = mon.observed_alpha
+        if self.ckpt is not None:
+            # mirror the background-writer state each step (before the
+            # save below can consume it): a pending failure keeps
+            # re-noting until consumed; once the writer is clean again
+            # and no new failure is noted, the signal self-heals
+            mon.note_ckpt_error(self.ckpt.error)
+            mon.note_ckpt_retries(self.ckpt.total_retries)
+        if self.ckpt is not None and self.step % self.tcfg.ckpt_every == 0:
+            # a failed *previous* background write re-raises out of
+            # save()'s internal wait(); periodic checkpointing is not
+            # worth aborting a healthy run — surface it and try again
+            # next period (the final end-of-run save still raises)
             try:
-                # the live mesh is the context of every step: after a remesh
-                # it differs from whatever mesh the caller entered, and the
-                # step's shard_maps must trace against the mesh they name
-                with (compat.use_mesh(self.mesh) if self.mesh is not None
-                      else contextlib.nullcontext()):
-                    self.state, metrics = self.train_step(self.state, batch)
-                if (self.step + 1) % self.tcfg.metrics_host_every == 0:
-                    metrics = {k: float(v) for k, v in metrics.items()
-                               if getattr(v, "ndim", 0) == 0}
-                    # decode the heartbeat slots out of the fused metrics
-                    # psum into the attribution state (and out of the
-                    # user-visible metrics — stats carries the EMAs)
-                    beats = {int(k[9:]): metrics.pop(k)
-                             for k in list(metrics)
-                             if k.startswith("heartbeat")
-                             and k[9:].isdigit()}
-                    if beats:
-                        self.monitor.note_heartbeats(beats)
-                    self.profile.update(metrics)
-                    # overflow is visible host-side every profiled step, not
-                    # just when (or if) the growth replan fires; restricted
-                    # to real sparse tables (the MoE router also emits a
-                    # *_dropped scalar that is not buffer overflow)
-                    self.monitor.note_overflow(
-                        self.profile.dropped(self.plan.table_methods))
-                retries = 0
-            except Exception:  # failure path: restore + retry
-                retries += 1
-                log.exception("step %d failed (retry %d/%d)",
-                              self.step, retries, self.tcfg.max_retries)
-                if retries > self.tcfg.max_retries or self.ckpt is None:
-                    raise
-                try:
-                    self.ckpt.wait()
-                except Exception:
-                    log.exception("in-flight checkpoint also failed")
-                if latest_step(self.tcfg.ckpt_dir) is None:
-                    # no committed checkpoint to fall back on — and the
-                    # failed call may already have consumed the donated
-                    # state buffers, so retrying on self.state would feed
-                    # the step poisoned memory. Rebuild from scratch.
-                    log.warning("no committed checkpoint: reinitializing "
-                                "state from seed %d at step 0",
-                                self.run_cfg.seed)
-                    self.train_step, self.state, self.shardings = build_step(
-                        self.model, self.optimizer, self.rt, self.plan,
-                        None, seed=self.run_cfg.seed)
-                    self.step = 0
-                    self.monitor.note_recovery()
-                else:
-                    self.maybe_restore()
-                continue
-            stats = self.monitor.stop(tokens=tokens_per_step)
-            self.step += 1
-            if self.tcfg.replan_every and \
-                    self.step % self.tcfg.replan_every == 0:
-                self.maybe_replan()
-                # this step's stats must reflect a replan it triggered
-                stats["replans"] = self.monitor.replans
-                if self.monitor.observed_alpha is not None:
-                    stats["observed_alpha"] = self.monitor.observed_alpha
-            if self.ckpt is not None:
-                # mirror the background-writer state each step (before the
-                # save below can consume it): a pending failure keeps
-                # re-noting until consumed; once the writer is clean again
-                # and no new failure is noted, the signal self-heals
-                self.monitor.note_ckpt_error(self.ckpt.error)
-                self.monitor.note_ckpt_retries(self.ckpt.total_retries)
-            if self.ckpt is not None and self.step % self.tcfg.ckpt_every == 0:
-                # a failed *previous* background write re-raises out of
-                # save()'s internal wait(); periodic checkpointing is not
-                # worth aborting a healthy run — surface it and try again
-                # next period (the final end-of-run save still raises)
-                try:
+                with mon.span(spans.CHECKPOINT):
                     self.ckpt.save(self.step, self._canonical_state(),
                                    extra=self._ckpt_extra())
-                except Exception as e:
-                    log.exception("checkpoint at step %d failed", self.step)
-                    self.monitor.note_ckpt_error(e)
-            if self.monitor.remesh_suggested and self.tcfg.remesh_on_straggle:
-                if self._auto_remesh() is not None:
-                    stats["remeshes"] = self.monitor.remeshes
-                    if self.mesh is not None:
-                        stats["mesh"] = dict(self.mesh.shape)
-            elif self.monitor.straggler_suspected:
-                log.warning("sustained step-time regression at step %d — "
-                            "straggler suspected; consider remesh() or "
-                            "remesh_on_straggle=True", self.step)
-            elif self.tcfg.stale_on_jitter and \
-                    getattr(self.run_cfg, "max_staleness", 0) > 0:
-                # the jitter fallback sits strictly below eviction: only
-                # consulted when no straggler escalation is in flight
-                if self.monitor.stale_suggested:
-                    flipped = self._flip_stale(True)
-                elif self.monitor.stale_recovered:
-                    flipped = self._flip_stale(False)
-                else:
-                    flipped = None
-                if flipped is not None:
-                    stats["stale_flips"] = self.monitor.stale_flips
-                    stats["stale_mode"] = self.monitor._stale_on
-            if on_metrics is not None:
-                on_metrics(self.step, {**metrics, **stats})
-            elif self.step % self.tcfg.log_every == 0:
-                log.info("step %d loss %.4f %.0f tok/s", self.step,
-                         metrics.get("loss", float("nan")),
-                         stats["tokens_per_s"])
-        if self.ckpt is not None:
-            self.ckpt.save(self.step, self._canonical_state(),
-                           extra=self._ckpt_extra())
-            self.ckpt.wait()
-        return self.state
+            except Exception as e:
+                log.exception("checkpoint at step %d failed", self.step)
+                mon.note_ckpt_error(e)
+        if mon.remesh_suggested and self.tcfg.remesh_on_straggle:
+            if self._auto_remesh() is not None:
+                stats["remeshes"] = mon.remeshes
+                if self.mesh is not None:
+                    stats["mesh"] = dict(self.mesh.shape)
+        elif mon.straggler_suspected:
+            log.warning("sustained step-time regression at step %d — "
+                        "straggler suspected; consider remesh() or "
+                        "remesh_on_straggle=True", self.step)
+        elif self.tcfg.stale_on_jitter and \
+                getattr(self.run_cfg, "max_staleness", 0) > 0:
+            # the jitter fallback sits strictly below eviction: only
+            # consulted when no straggler escalation is in flight
+            if mon.stale_suggested:
+                flipped = self._flip_stale(True)
+            elif mon.stale_recovered:
+                flipped = self._flip_stale(False)
+            else:
+                flipped = None
+            if flipped is not None:
+                stats["stale_flips"] = mon.stale_flips
+                stats["stale_mode"] = mon._stale_on

@@ -442,3 +442,94 @@ def parse_collectives(hlo_text: str) -> HloSummary:
 
 def collective_bytes_by_kind(hlo_text: str) -> dict[str, float]:
     return analyze_hlo(hlo_text).collective_by_kind
+
+
+# ---------------------------------------------------------------------------
+# step phases
+# ---------------------------------------------------------------------------
+# The training step opens a ``jax.named_scope`` per phase: ``FORWARD`` around
+# the loss (core/transform.py, core/buckets.py), ``OPTIMIZER`` around the
+# update, clipping included (core/transform.py), and ``EXCHANGE`` around each
+# explicit collective of the gradient exchange with its packing, casting and
+# relayout (core/buckets.py, core/embedding.py). A scope changes only the
+# ``op_name`` metadata of the ops it holds. The backward needs no scope: JAX
+# names each of its ops ``transpose(jvp(...))``.
+
+FORWARD, BACKWARD, OPTIMIZER, EXCHANGE = (
+    "forward", "backward", "optimizer", "exchange")
+
+
+def _scope_in(name: str):
+    # a path component is the scope itself or a transform of it,
+    # e.g. ``jvp(forward)`` or ``transpose(jvp(forward))``
+    return re.compile(r"(?:^|[/(])" + name + r"(?:[)/]|$)")
+
+
+_PHASE_RULES = ((EXCHANGE, _scope_in(EXCHANGE)),
+                (OPTIMIZER, _scope_in(OPTIMIZER)),
+                (BACKWARD, re.compile(r"transpose\(")),
+                (FORWARD, _scope_in(FORWARD)))
+
+
+def step_phase(op_name: str) -> str | None:
+    """The phase of a compiled step's op from its ``op_name`` metadata: the
+    first rule that matches, of ``exchange`` scope, ``optimizer`` scope,
+    ``transpose(`` (the backward), ``forward`` scope. None: unattributed."""
+    for phase, rule in _PHASE_RULES:
+        if rule.search(op_name):
+            return phase
+    return None
+
+
+_PHASE_INSTR = re.compile(r"^\s*(ROOT\s+)?%?([\w.\-]+)\s*=\s*(.*)$")
+_OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
+_CALLS = re.compile(r"\b(?:calls|body)=%?([\w.\-]+)")
+_REF = re.compile(r"%([\w.\-]+)")
+
+
+def step_phases(hlo_text: str) -> dict:
+    """``{instruction name: phase}`` for the instructions of a compiled
+    module's text (``.compile().as_text()``) that have one. An instruction
+    takes the phase of its own op_name. One whose op_name names no phase,
+    or that has none (a backend may leave a fusion, a convert or a layout
+    copy without metadata), takes that of the computation it calls (its
+    root's, else its instructions' most common), else that of its first
+    operand that has one, else that of its first user that has one (a
+    buffer of zeros is the work of the phase that fills it)."""
+    out, comps, cur, users, todo = {}, {}, None, {}, []
+    for line in hlo_text.splitlines():
+        if line.rstrip().endswith("{") and " = " not in line:
+            name = line.split()[1 if line.startswith("ENTRY") else 0]
+            cur = comps[name.lstrip("%")] = {"root": None, "phases": []}
+            continue
+        m = _PHASE_INSTR.match(line)
+        if not m or cur is None:
+            continue
+        name, rest = m.group(2), m.group(3)
+        refs = _REF.findall(rest.partition(", metadata=")[0])
+        for r in refs:
+            users.setdefault(r, []).append(name)
+        op = _OP_NAME.search(rest)
+        phase = step_phase(op.group(1)) if op else None
+        if phase is None:
+            call = _CALLS.search(rest)
+            called = comps.get(call.group(1)) if call else None
+            if called:
+                phase = out.get(called["root"]) or max(
+                    set(called["phases"]), key=called["phases"].count,
+                    default=None)
+            if phase is None:
+                phase = next((out[r] for r in refs if r in out), None)
+        if phase is not None:
+            out[name] = phase
+            cur["phases"].append(phase)
+        else:
+            todo.append(name)
+        if m.group(1):
+            cur["root"] = name
+    for name in reversed(todo):
+        phase = next((out[u] for u in users.get(name, ()) if u in out),
+                     None)
+        if phase is not None:
+            out[name] = phase
+    return out

@@ -106,7 +106,6 @@ res = {
     "resumed_from": second[0][0],
     "ref_losses": [float(m["loss"]) for _, m in ref],
     "split_losses": [float(m["loss"]) for _, m in first + second],
-    "apply_seconds": float(ref[-1][1].get("apply_seconds", -1.0)),
     "exchange": "exchange" in ref[-1][1],
 }
 print("RESULT:" + json.dumps(res))
@@ -118,11 +117,9 @@ def test_fused_trainer_checkpoint_trajectory_continuity():
     """Checkpoints written by a fused trainer hold the canonical per-param
     layout: a run interrupted at step 4 and resumed by a fresh trainer
     reproduces the uninterrupted 8-step f32 trajectory exactly (restore
-    lands in a canonical template, then re-fuses onto the live plan). The
-    analytic apply cost is surfaced in the step stats."""
+    lands in a canonical template, then re-fuses onto the live plan)."""
     res = distributed_run(CKPT_CODE, devices=8, timeout=900)
     assert all(res["fused"]), res                # fused layout was live
     assert res["resumed_from"] == 5, res         # restore picked up step 4
     assert res["split_losses"] == res["ref_losses"], res
-    assert res["apply_seconds"] > 0, res
     assert res["exchange"], res
