@@ -221,8 +221,9 @@ class RunConfig:
     wire_dtype_auto: bool = False
     wire_outlier_ratio: float = 64.0
     # fused bucket-apply (optim/optimizer.py update_fused): when the bucketed
-    # exchange is active, keep adamw/momentum state as flat per-bucket f32
-    # buffers and apply the update straight from the post-psum wire buffer —
+    # exchange is active, keep adamw/momentum state as per-bucket f32
+    # buffers (flat; a one-member bucket keeps its leaf's shape) and apply
+    # the update straight from the post-psum wire buffer —
     # no unflatten -> per-param update -> reflatten round trip. Bit-identical
     # to the per-param path at f32; eligibility also needs zero_stage 0 and
     # opau (core/buckets.py fused_apply_eligible).

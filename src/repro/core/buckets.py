@@ -14,7 +14,14 @@ is written explicitly —
 
   * dense (method == allreduce) gradients are flattened into a few flat
     wire-dtype buffers of at most ``RunConfig.bucket_bytes`` each, grouped
-    by (method, exchange dtype, pspec); each buffer rides ONE psum,
+    by (method, exchange dtype, pspec); each buffer rides ONE psum. A
+    bucket with one member — every leaf larger than ``bucket_bytes`` sits
+    alone — keeps that leaf's shape instead (``Bucket.shaped``): it has
+    nothing to concatenate, and on the TPU the flat layout of a tiled 2-D
+    leaf is a physical relayout, not a bitcast. Its wire buffer, its
+    post-psum buffer and its fused optimizer state stay in the leaf's
+    shape; the two-level schedule, which pads and scatters a flat buffer,
+    leaves it flat, and the ``overlap=False`` pin flattens its wire buffer,
   * the loss and every scalar metric ride a single fused scalar psum,
   * the sparse push keeps its own schedule: the embedding custom_vjp runs
     its per-device body directly on the live named axes (EmbedCtx.manual).
@@ -97,6 +104,15 @@ class Bucket:
     nbytes: int       # fused buffer wire bytes
     schedule: str = "ring"     # ring | two_level (cost_model argmin)
 
+    @property
+    def shaped(self) -> bool:
+        """Does this bucket keep its member leaf's own shape end to end?
+        A one-member ring bucket has nothing to concatenate, and on the TPU
+        a flat layout of a tiled 2-D leaf is a physical relayout, not a
+        bitcast — so its wire buffer, its post-psum buffer and its fused
+        optimizer state all stay in the leaf's shape."""
+        return len(self.idx) == 1 and self.schedule == "ring"
+
 
 @dataclass
 class BucketPlan:
@@ -136,6 +152,9 @@ class BucketPlan:
             "n_collectives_unbucketed": self.n_params,
             "n_two_level": sum(1 for b in self.buckets
                                if b.schedule == "two_level"),
+            # one-member ring buckets, exchanged and applied in their
+            # member leaf's shape (Bucket.shaped)
+            "n_shaped_buckets": sum(1 for b in self.buckets if b.shaped),
             "hosts": self.hosts,
             "overlap": self.overlap,
             # sparse row-buffer pushes issued at gradient readiness inside
@@ -355,8 +374,14 @@ def _exchange_bucket(b: Bucket, gparts: list, scale: float, bp: BucketPlan,
     wire-dtype cast → psum (ring or two-level) → slice back. ``gparts`` are
     the members' local gradient leaves; returns (exchanged leaves cast back
     to the member dtypes, (|g|inf, rms) census scalars or None, the
-    post-psum flat wire buffer — the fused bucket-apply path feeds it to
-    the optimizer directly, pin excluded).
+    post-psum wire buffer — the fused bucket-apply path feeds it to the
+    optimizer directly, pin excluded).
+
+    A shaped bucket (``Bucket.shaped``: one member, ring schedule) skips the
+    flatten and the slice-back: it scales, casts and psums in the leaf's own
+    shape, and its post-psum buffer is the exchanged leaf's shape. The
+    ``overlap=False`` pin is appended to a flat buffer, so there even a
+    shaped bucket flattens (the scheduling baseline, not the hot path).
 
     The census reads what rides the wire, pre-cast; downstream the scalars
     join the fused metrics psum so the host sees the replica-*mean* of the
@@ -370,7 +395,10 @@ def _exchange_bucket(b: Bucket, gparts: list, scale: float, bp: BucketPlan,
     idiomatic pin, but the CPU backend expands barriers away before
     scheduling, and the regression baseline must hold everywhere."""
     wdt = jnp.dtype(b.key[1])
-    parts = [(g.astype(jnp.float32) * scale).reshape(-1) for g in gparts]
+    flat = not b.shaped or pin is not None
+    parts = [g.astype(jnp.float32) * scale for g in gparts]
+    if flat:
+        parts = [x.reshape(-1) for x in parts]
     buf32 = jnp.concatenate(parts) if len(parts) > 1 else parts[0]
     stats = None
     if census:
@@ -383,6 +411,8 @@ def _exchange_bucket(b: Bucket, gparts: list, scale: float, bp: BucketPlan,
         buf = _two_level_psum(wire, bp.batch_axes, bp.dims.local_replicas)
     else:
         buf = jax.lax.psum(wire, bp.batch_axes)   # ONE dense collective
+    if not flat:
+        return [buf.astype(gparts[0].dtype)], stats, buf
     out, off = [], 0
     for g, sz in zip(gparts, b.sizes):
         out.append(buf[off:off + sz].reshape(g.shape).astype(g.dtype))
@@ -433,7 +463,10 @@ def make_bucketed_value_and_grad(model, rt, plan: Plan) -> Callable:
     # fused bucket-apply: the optimizer wants the post-psum flat buffers
     # themselves (optim/optimizer.py update_fused), so the step also
     # returns them — under overlap they leave the backward through the tap
-    # tokens' cotangents (wire -> f32 is exact for every wire dtype)
+    # tokens' cotangents (wire -> f32 is exact for every wire dtype). A
+    # shaped bucket's buffer is its exchanged leaf itself: the wire dtype
+    # is never wider than the parameter's (_exchange_dtype), so wire ->
+    # param dtype -> f32 reads the same values as wire -> f32
     want_bufs = bool(getattr(plan, "fused_apply", False))
     # sparse tables that kept their own exchange: the row-buffer census
     # targets these (their grads never transit a bucket, so without this
@@ -455,7 +488,9 @@ def make_bucketed_value_and_grad(model, rt, plan: Plan) -> Callable:
                     and rt.sparse_defer_exact(p.name)}
 
     def _make_tap(b: Bucket):
-        total = sum(b.sizes)
+        # the flat buffer rides the token's cotangent out of the backward;
+        # a shaped bucket's buffer is its exchanged leaf
+        carry = sum(b.sizes) if want_bufs and not b.shaped else 0
         @jax.custom_vjp
         def tap(leaves, token):
             return leaves
@@ -466,11 +501,11 @@ def make_bucketed_value_and_grad(model, rt, plan: Plan) -> Callable:
                                               grad_census)
             tok_ct = (jnp.stack(stats) if stats is not None
                       else jnp.zeros((2,), jnp.float32))
-            if want_bufs:
+            if carry:
                 tok_ct = jnp.concatenate([tok_ct, buf.astype(jnp.float32)])
             return tuple(ex), tok_ct
         tap.defvjp(fwd, bwd)
-        return tap, 2 + (total if want_bufs else 0)
+        return tap, 2 + carry
 
     taps_and_sizes = [_make_tap(b) for b in bp.buckets]
     taps = [t for t, _ in taps_and_sizes]
@@ -506,7 +541,8 @@ def make_bucketed_value_and_grad(model, rt, plan: Plan) -> Callable:
             gleaves, gtree = jax.tree_util.tree_flatten(grads)
             out = list(gleaves)       # bucketed leaves already exchanged
             if want_bufs:
-                bufs = [tgrads[k][2:] for k in range(len(bp.buckets))]
+                bufs = [out[b.idx[0]] if b.shaped else tgrads[k][2:]
+                        for k, b in enumerate(bp.buckets)]
             if grad_census:
                 for k in range(len(bp.buckets)):
                     metrics[f"gbucket{k}_gmax"] = tgrads[k][0]
@@ -533,7 +569,8 @@ def make_bucketed_value_and_grad(model, rt, plan: Plan) -> Callable:
                 for j, i in enumerate(b.idx):
                     out[i] = ex[j]
                 if want_bufs:
-                    bufs.append(buf.astype(jnp.float32))
+                    bufs.append(ex[0] if b.shaped
+                                else buf.astype(jnp.float32))
                 if stats is not None:
                     metrics[f"gbucket{k}_gmax"] = stats[0]
                     metrics[f"gbucket{k}_grms"] = stats[1]

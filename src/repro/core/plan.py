@@ -167,7 +167,7 @@ class Plan:
     embed_method: str = "ps"           # the "embed" table's exchange method
     bucket_plan: Any = None            # core/buckets.py BucketPlan (None =
                                        # per-tensor dense collectives)
-    fused_apply: bool = False          # optimizer reads the flat bucket
+    fused_apply: bool = False          # optimizer reads the bucket
                                        # buffers directly (fused m/v/EMA
                                        # layout; optim/optimizer.py)
     table_tiles: dict = field(default_factory=dict)  # name -> (gather_block,

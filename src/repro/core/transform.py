@@ -259,9 +259,10 @@ def state_shardings(plan: Plan, state_like: TrainState):
     """TrainState shardings (moments follow opt_pspec; ema follows param).
 
     Fused bucket-apply states (optim/optimizer.py ``fuse_state``) hold each
-    moment as {"bucket": [flat f32 buffers], "leaf": per-param tree with
-    None at bucketed positions}: the buffers are post-psum replicated values
-    (fused apply needs zero_stage 0), so they shard as P(); the surviving
+    moment as {"bucket": [f32 buffers, flat or, for a one-member bucket,
+    in the leaf's shape], "leaf": per-param tree with None at bucketed
+    positions}: the buffers are post-psum replicated values (fused apply
+    needs zero_stage 0), so they shard as P() at any rank; the surviving
     unbucketed leaves keep their planned pspecs, and the None placeholders
     mirror over to the sharding tree (empty subtrees carry no sharding).
     """
@@ -424,7 +425,7 @@ def make_train_step(model: Model, optimizer: Optimizer, rt: Runtime,
     collectives (already at the wire dtype — the OPSW cast lives in the
     exchange), and the optimizer consumes them per-tensor as always — or,
     when the plan stamps ``fused_apply``, bucket-natively: the exchange also
-    hands back the post-psum flat buffers and ``optimizer.update_fused``
+    hands back the post-psum bucket buffers and ``optimizer.update_fused``
     applies straight from them against the fused state layout.
     """
     stale_rule = _make_staleness_rule(plan, rt)

@@ -24,7 +24,11 @@ pure memory-traffic tax. ``fuse_state``/``unfuse_state`` re-lay the m/v/EMA
 state as one flat f32 buffer per bucket (params stay per-leaf — the model
 needs them), and ``Optimizer.update_fused`` reads each post-psum buffer
 directly against that layout: one elementwise chain per bucket instead of
-one per parameter. Bit-identical to ``update`` at every dtype: the per-leaf
+one per parameter. A shaped bucket (``Bucket.shaped``: one member, ring
+schedule) keeps its buffers in the member leaf's shape: with nothing to
+concatenate, a flat buffer would only re-lay the leaf, which on the TPU is
+a physical copy of a tiled 2-D array, not a bitcast; its chain is the
+per-leaf chain. Bit-identical to ``update`` at every dtype: the per-leaf
 reference is elementwise, and every fused op applies the same cast chain to
 the same linear values (the global-norm partial sums accumulate in the same
 leaf order). Param-wise weight-decay masks become per-bucket segment
@@ -96,13 +100,20 @@ def _flat_with_none(tree):
     return jax.tree_util.tree_flatten(tree, is_leaf=lambda x: x is None)
 
 
+def _member(buf, b, off: int, sz: int):
+    """One member's extent of a bucket buffer: the whole buffer of a shaped
+    bucket (already in the leaf's shape), else its flat ``[off, off+sz)``
+    segment."""
+    return buf if b.shaped else buf[off:off + sz]
+
+
 def fuse_state(state: Optional[TrainState], bp) -> Optional[TrainState]:
     """Per-param -> bucket-fused optimizer-state layout: m/v/EMA become one
-    flat f32 buffer per bucket ({"bucket": [...], "leaf": tree}); bucketed
-    positions in the leaf tree hold ``None`` placeholders so the structure
-    still mirrors params positionally (flatten with ``_flat_with_none``).
-    Exact — buffers are concatenations of the per-leaf f32 values in bucket
-    member order."""
+    f32 buffer per bucket ({"bucket": [...], "leaf": tree}) — flat, or the
+    member leaf's own shape for a shaped bucket; bucketed positions in the
+    leaf tree hold ``None`` placeholders so the structure still mirrors
+    params positionally (flatten with ``_flat_with_none``). Exact — buffers
+    are concatenations of the per-leaf f32 values in bucket member order."""
     if state is None or bp is None or is_fused(state):
         return state
 
@@ -110,8 +121,9 @@ def fuse_state(state: Optional[TrainState], bp) -> Optional[TrainState]:
         if tree is None:
             return None
         leaves, tdef = jax.tree_util.tree_flatten(tree)
-        bufs = [jnp.concatenate([leaves[i].astype(jnp.float32).reshape(-1)
-                                 for i in b.idx])
+        bufs = [leaves[b.idx[0]].astype(jnp.float32) if b.shaped
+                else jnp.concatenate([leaves[i].astype(jnp.float32)
+                                      .reshape(-1) for i in b.idx])
                 for b in bp.buckets]
         for b in bp.buckets:
             for i in b.idx:
@@ -138,7 +150,7 @@ def unfuse_state(state: Optional[TrainState], bp) -> Optional[TrainState]:
         for k, b in enumerate(bp.buckets):
             buf, off = tree["bucket"][k], 0
             for i, sz in zip(b.idx, b.sizes):
-                leaves[i] = buf[off:off + sz].reshape(pleaves[i].shape)
+                leaves[i] = _member(buf, b, off, sz).reshape(pleaves[i].shape)
                 off += sz
         return jax.tree_util.tree_unflatten(tdef, leaves)
 
@@ -148,9 +160,12 @@ def unfuse_state(state: Optional[TrainState], bp) -> Optional[TrainState]:
 
 def _wd_segment(b, weight_decay: float, mask_leaves: Optional[list]):
     """Per-bucket weight-decay segment: the param-wise mask expanded over
-    the bucket's member extents (scalar when the mask is uniform/absent)."""
+    the bucket's member extents (scalar when the mask is absent or the
+    bucket has one member)."""
     if not mask_leaves:
         return weight_decay
+    if len(b.idx) == 1:
+        return weight_decay * float(mask_leaves[b.idx[0]])
     return jnp.concatenate([
         jnp.full((sz,), float(weight_decay) * float(mask_leaves[i]),
                  jnp.float32) for i, sz in zip(b.idx, b.sizes)])
@@ -243,8 +258,9 @@ def adamw(lr: float | Callable = 1e-3, b1: float = 0.9, b2: float = 0.95,
         return TrainState(step, params, m, v, ema), metrics
 
     def update_fused(state: TrainState, grads, bufs, bp):
-        """Bucket-native adamw: each all-reduced flat buffer drives one
-        elementwise chain against the fused m/v/EMA buffers; only the
+        """Bucket-native adamw: each all-reduced bucket buffer (flat, or in
+        its leaf's shape for a shaped bucket) drives one elementwise chain
+        against the fused m/v/EMA buffers; only the
         unbucketed leaves (sparse tables) walk the per-leaf path. The cast
         chain per bucket (wire f32 -> param dtype -> f32, clip, moments,
         param slice-back) replays the per-param reference op for op, so the
@@ -267,8 +283,9 @@ def adamw(lr: float | Callable = 1e-3, b1: float = 0.9, b2: float = 0.95,
                     # shape (the exchange slice-back reshapes first), and a
                     # flat 1-D reduction associates differently at size
                     k, off, sz = seg[i]
-                    sq.append(jnp.sum(jnp.square(
-                        gbufs[k][off:off + sz].reshape(pleaves[i].shape))))
+                    sq.append(jnp.sum(jnp.square(_member(
+                        gbufs[k], bp.buckets[k], off, sz)
+                        .reshape(pleaves[i].shape))))
                 else:
                     sq.append(jnp.sum(jnp.square(
                         gleaves[i].astype(jnp.float32))))
@@ -303,11 +320,12 @@ def adamw(lr: float | Callable = 1e-3, b1: float = 0.9, b2: float = 0.95,
             # bucket-wide update intermediate
             wd_seg = (_wd_segment(b, weight_decay, mask_leaves)
                       if weight_decay else None)
-            off, pnew32 = 0, []
+            off, enew = 0, []
             for i, sz in zip(b.idx, b.sizes):
-                p32 = pleaves[i].astype(jnp.float32).reshape(-1)
-                u = (m[off:off + sz] / bc1) \
-                    / (jnp.sqrt(v[off:off + sz] / bc2) + eps)
+                mi = _member(m, b, off, sz)
+                p32 = pleaves[i].astype(jnp.float32).reshape(mi.shape)
+                u = (mi / bc1) \
+                    / (jnp.sqrt(_member(v, b, off, sz) / bc2) + eps)
                 if wd_seg is not None:
                     w = wd_seg if jnp.ndim(wd_seg) == 0 \
                         else wd_seg[off:off + sz]
@@ -315,14 +333,15 @@ def adamw(lr: float | Callable = 1e-3, b1: float = 0.9, b2: float = 0.95,
                 pn = p32 - lr_t * u
                 new_p[i] = pn.reshape(pleaves[i].shape).astype(pdt)
                 if emaB is not None:
-                    pnew32.append(pn)
+                    # per member, as the per-leaf chain: one chain over
+                    # the concatenated bucket rounds differently on the CPU
+                    enew.append(_member(emaB[k], b, off, sz) * ema_decay
+                                + pn.astype(pdt).astype(jnp.float32)
+                                * (1 - ema_decay))
                 off += sz
             if emaB is not None:
-                pn = (jnp.concatenate(pnew32) if len(pnew32) > 1
-                      else pnew32[0])
-                emaB[k] = (emaB[k] * ema_decay
-                           + pn.astype(pdt).astype(jnp.float32)
-                           * (1 - ema_decay))
+                emaB[k] = (jnp.concatenate(enew) if len(enew) > 1
+                           else enew[0])
         mL, mdef = _flat_with_none(state.m["leaf"])
         vL = _flat_with_none(state.v["leaf"])[0]
         emaL = (_flat_with_none(state.ema["leaf"])[0]
@@ -397,8 +416,9 @@ def momentum(lr: float | Callable = 1e-2, mu: float = 0.9,
                 if i in seg:
                     # leaf-shaped reduction — see adamw.update_fused
                     k, off, sz = seg[i]
-                    sq.append(jnp.sum(jnp.square(
-                        gbufs[k][off:off + sz].reshape(pleaves[i].shape))))
+                    sq.append(jnp.sum(jnp.square(_member(
+                        gbufs[k], bp.buckets[k], off, sz)
+                        .reshape(pleaves[i].shape))))
                 else:
                     sq.append(jnp.sum(jnp.square(
                         gleaves[i].astype(jnp.float32))))
@@ -419,20 +439,22 @@ def momentum(lr: float | Callable = 1e-2, mu: float = 0.9,
         for k, b in enumerate(bp.buckets):
             pdt = pleaves[b.idx[0]].dtype
             mB[k] = mu * mB[k] + gbufs[k]
-            off, pnew32 = 0, []
+            off, enew = 0, []
             for i, sz in zip(b.idx, b.sizes):
-                p32 = pleaves[i].astype(jnp.float32).reshape(-1)
-                pn = p32 - lr_t * mB[k][off:off + sz]
+                mi = _member(mB[k], b, off, sz)
+                pn = pleaves[i].astype(jnp.float32).reshape(mi.shape) \
+                    - lr_t * mi
                 new_p[i] = pn.reshape(pleaves[i].shape).astype(pdt)
                 if emaB is not None:
-                    pnew32.append(pn)
+                    # per member, as the per-leaf chain: one chain over
+                    # the concatenated bucket rounds differently on the CPU
+                    enew.append(_member(emaB[k], b, off, sz) * ema_decay
+                                + pn.astype(pdt).astype(jnp.float32)
+                                * (1 - ema_decay))
                 off += sz
             if emaB is not None:
-                pn = (jnp.concatenate(pnew32) if len(pnew32) > 1
-                      else pnew32[0])
-                emaB[k] = (emaB[k] * ema_decay
-                           + pn.astype(pdt).astype(jnp.float32)
-                           * (1 - ema_decay))
+                emaB[k] = (jnp.concatenate(enew) if len(enew) > 1
+                           else enew[0])
         mL, mdef = _flat_with_none(state.m["leaf"])
         emaL = (_flat_with_none(state.ema["leaf"])[0]
                 if state.ema is not None else None)

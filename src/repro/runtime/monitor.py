@@ -406,6 +406,9 @@ class StepMonitor:
             # overlap-issued inside the backward
             if "n_two_level" in self.exchange:
                 stats["n_two_level"] = self.exchange["n_two_level"]
+            # buckets exchanged and applied in their one member's shape
+            if "n_shaped_buckets" in self.exchange:
+                stats["n_shaped_buckets"] = self.exchange["n_shaped_buckets"]
             if "overlap" in self.exchange:
                 stats["overlap"] = self.exchange["overlap"]
             # sparse row-buffer pushes issued at gradient readiness inside

@@ -163,3 +163,40 @@ def test_single_host_mesh_keeps_flat_ring_even_with_profile(tmp_path):
     bp = buckets.assign_buckets(plan, rt)
     assert bp.hosts == 1
     assert all(b.schedule == "ring" for b in bp.buckets)
+
+
+def test_stats_count_exactly_the_one_member_ring_buckets(tmp_path):
+    """``n_shaped_buckets`` counts the buckets that keep their member's
+    shape: one member on the ring schedule. A leaf over ``bucket_bytes``
+    sits alone; small leaves share; a one-member two-level bucket stays
+    flat and is not counted."""
+    leaves = [leaf("big0", (1024, 1024)), leaf("big1", (1024, 1024))] + \
+        [leaf(f"w{i}", (64, 64)) for i in range(8)]
+    bp = buckets.assign_buckets(fake_plan(leaves, MESH), fake_rt(MESH))
+    shaped = [b for b in bp.buckets if b.shaped]
+    assert sorted(b.idx for b in shaped) == [(0,), (1,)]
+    assert all(len(b.idx) == 8 for b in bp.buckets if not b.shaped)
+    assert bp.stats()["n_shaped_buckets"] == 2
+
+    prof = tmp_path / "hw.json"
+    prof.write_text('{"inter_bw": 12.5e9, "inter_latency": 10e-6}')
+    mesh = fake_mesh(pod=2, data=4, model=1)
+    plan = fake_plan([leaf("big", (512, 512)), leaf("small", (8, 8))], mesh)
+    rt = fake_rt(mesh, batch=("pod", "data"), replicas=8,
+                 bucket_bytes=1 << 20)
+    rt.run_cfg.hw_profile = str(prof)
+    bp = buckets.assign_buckets(plan, rt)
+    assert [(b.idx, b.schedule, b.shaped) for b in bp.buckets] == \
+        [((1,), "ring", True), ((0,), "two_level", False)]
+    s = bp.stats()
+    assert s["n_shaped_buckets"] == 1 and s["n_two_level"] == 1
+
+
+def test_monitor_surfaces_shaped_bucket_count():
+    from repro.runtime.monitor import StepMonitor
+    bp = buckets.assign_buckets(
+        fake_plan([leaf("w0", (1024, 1024))], MESH), fake_rt(MESH))
+    mon = StepMonitor()
+    mon.note_exchange(bp.stats())
+    mon.start()
+    assert mon.stop(tokens=8)["n_shaped_buckets"] == 1

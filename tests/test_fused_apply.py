@@ -123,3 +123,78 @@ def test_fused_trainer_checkpoint_trajectory_continuity():
     assert res["resumed_from"] == 5, res         # restore picked up step 4
     assert res["split_losses"] == res["ref_losses"], res
     assert res["exchange"], res
+
+
+SHAPED_CODE = """
+import re
+from repro.configs import get_config, reduced, RunConfig, ShapeConfig
+from repro.core.transform import get_runner
+from repro.data import SyntheticLM
+
+cfg = reduced(get_config("parallax-lm"), vocab=256, d_model=32, d_ff=64,
+              layers=1)
+shape = ShapeConfig("tiny", seq_len=8, global_batch=8, kind="train")
+kw = dict(attention_impl="naive", remat="none", param_dtype="float32",
+          compute_dtype="float32", wire_dtype="float32")
+ds = SyntheticLM(cfg.vocab_size, 8, 8)
+
+def all_reduce_operands(run):
+    # every operand shape of every all-reduce: XLA's combiner may merge
+    # bucket psums into one variadic all-reduce, each operand keeps its own
+    txt = run.train_step.lower(run.state, ds.batch(0)).compile().as_text()
+    out = []
+    for line in txt.splitlines():
+        m = re.search(r"= (.*?) all-reduce(?:-start)?\\(", line)
+        if m:
+            out += [[int(d) for d in dims.split(",") if d]
+                    for dims in re.findall(r"[a-z0-9]+\\[([0-9,]*)\\]",
+                                           m.group(1))]
+    return out
+
+mesh = make_mesh((4, 1), ("data", "model"))
+with use_mesh(mesh):
+    # 4 KiB: every 2-D leaf of this model sits alone in its bucket;
+    # 1 MiB: they all share one flat bucket
+    runs = {"shaped": RunConfig(**kw, bucket_bytes=4096),
+            "unfused": RunConfig(**kw, bucket_bytes=4096, fused_apply=False),
+            "shared": RunConfig(**kw, bucket_bytes=1 << 20)}
+    out = {}
+    for name, rc in runs.items():
+        run = get_runner(cfg, shape, rc, mesh=mesh)
+        bp = run.plan.bucket_plan
+        shapes = [list(x.shape) for x in jax.tree.leaves(run.state.params)]
+        rec = {"members": [[shapes[i] for i in b.idx] for b in bp.buckets],
+               "shaped": [b.shaped for b in bp.buckets],
+               "n_shaped": bp.stats()["n_shaped_buckets"],
+               "fused": bool(run.plan.fused_apply),
+               "operands": all_reduce_operands(run)}
+        if run.plan.fused_apply:
+            rec["m_bucket"] = [list(x.shape) for x in run.state.m["bucket"]]
+        rec["losses"] = [float(run.run(ds.batch(i))["loss"])
+                         for i in range(2)]
+        out[name] = rec
+print("RESULT:" + json.dumps(out))
+"""
+
+
+@pytest.mark.distributed
+def test_one_member_bucket_keeps_leaf_shape_through_psum_and_apply():
+    """A one-member bucket never flattens: its all-reduce operand keeps the
+    2-D leaf's shape (no rank-1 all-reduce of that element count), its
+    fused m/v/EMA buffers keep it too, and the 2-step f32 losses equal the
+    per-param apply and a plan where the same leaves share a flat bucket."""
+    res = distributed_run(SHAPED_CODE, devices=4, timeout=900)
+    sh, shared = res["shaped"], res["shared"]
+    two_d = [m[0] for m, s in zip(sh["members"], sh["shaped"])
+             if s and len(m[0]) == 2]
+    assert sh["fused"] and two_d, res
+    assert sh["n_shaped"] == sum(sh["shaped"]) == len(sh["members"]), res
+    for leaf_shape in two_d:
+        n = leaf_shape[0] * leaf_shape[1]
+        assert leaf_shape in sh["operands"], res
+        assert [n] not in sh["operands"], res
+        assert leaf_shape in sh["m_bucket"], res
+    # the control: sharing a bucket puts those leaves on a flat buffer
+    assert shared["n_shaped"] == 0 and len(shared["members"]) == 1, res
+    assert not any(s in shared["operands"] for s in two_d), res
+    assert sh["losses"] == res["unfused"]["losses"] == shared["losses"], res

@@ -94,9 +94,12 @@ def test_ema_tracks_params():
 def _bucket_plan():
     """One bucket holding leaf 0 ('a', 32 elements); leaf 1 ('b/w') stays
     unbucketed — both the bucket-native and the surviving per-leaf path of
-    update_fused are exercised."""
+    update_fused are exercised. The bucket rides the two-level schedule,
+    which pads and scatters a flat buffer, so even with one member it keeps
+    the flat layout (a one-member ring bucket would be shaped: see
+    ``_mixed_plan``)."""
     b = Bucket(key=("allreduce", "float32", ()), idx=(0,), sizes=(32,),
-               nbytes=32 * 4)
+               nbytes=32 * 4, schedule="two_level")
     return BucketPlan(buckets=[b], batch_axes=("data",), replicas=1,
                       n_params=1, wire_bytes=b.nbytes, bucket_bytes=1 << 20)
 
@@ -139,9 +142,12 @@ def test_fused_update_bit_identical_f32(make_opt):
     bp = _bucket_plan()
     ref = opt.init(_params())
     fused = fuse_state(opt.init(_params()), bp)
-    # jit both, as the train step does: XLA canonicalizes the reshape
-    # between a leaf and its flat bucket segment, so the clip-norm
-    # reduction associates identically (eager dispatch would differ at ULP)
+    # jit both, as the train step does: on the CPU XLA canonicalizes the
+    # reshape between a leaf and its flat bucket segment to a bitcast, so
+    # the clip-norm reduction associates identically (eager dispatch would
+    # differ at ULP). On the TPU that reshape of a tiled 2-D leaf is a
+    # physical relayout, which is why a one-member ring bucket keeps its
+    # leaf's shape instead (test_fused_update_bit_identical_shaped_bucket)
     upd = jax.jit(opt.update)
     upd_fused = jax.jit(lambda s, g, bufs: opt.update_fused(s, g, bufs, bp))
     for step in range(3):
@@ -165,6 +171,111 @@ def test_fused_wd_mask_segments():
     bufs = [jnp.reshape(_grads()["a"], (-1,)).astype(jnp.float32)]
     fused, _ = opt.update_fused(fused, _grads(), bufs, bp)
     _assert_states_equal(ref, unfuse_state(fused, bp))
+
+
+# ---------------------------------------------------------------------------
+# shaped buckets: a one-member ring bucket keeps its leaf's shape
+# ---------------------------------------------------------------------------
+
+def _mixed_params():
+    k = jax.random.key(3)
+    shapes = {"a": (4, 8), "b": {"w": (8,)}, "c": (3,), "d": (2, 5)}
+    leaves, tdef = jax.tree.flatten(shapes, is_leaf=lambda x: isinstance(
+        x, tuple))
+    return jax.tree.unflatten(tdef, [
+        jax.random.normal(jax.random.fold_in(k, i), s, jnp.float32)
+        for i, s in enumerate(leaves)])
+
+
+def _mixed_grads(scale=1.0):
+    return jax.tree.map(
+        lambda p: scale * jax.random.normal(jax.random.key(p.size), p.shape,
+                                            jnp.float32), _mixed_params())
+
+
+def _mixed_plan(shaped=True):
+    """Leaves a (4, 8), b/w (8,), c (3,), d (2, 5), flattened 0..3. Bucket
+    0 holds the 2-D leaf 'a' alone: a shaped bucket on the ring schedule
+    (flat under ``shaped=False``, which puts it on the two-level schedule).
+    Bucket 1 holds 'd' and 'b/w', flat in reverse-topological member order;
+    'c' stays unbucketed."""
+    one = Bucket(key=("allreduce", "float32", ()), idx=(0,), sizes=(32,),
+                 nbytes=32 * 4, schedule="ring" if shaped else "two_level")
+    two = Bucket(key=("allreduce", "float32", ()), idx=(3, 1),
+                 sizes=(10, 8), nbytes=18 * 4)
+    return BucketPlan(buckets=[one, two], batch_axes=("data",), replicas=1,
+                      n_params=3, wire_bytes=one.nbytes + two.nbytes,
+                      bucket_bytes=64)
+
+
+def _exchanged_bufs(bp, grads):
+    """What the bucketed exchange hands the fused apply: a shaped bucket's
+    exchanged leaf itself, else the flat concatenation of its members."""
+    g = jax.tree.leaves(grads)
+    return [g[b.idx[0]] if b.shaped else jnp.concatenate(
+        [g[i].reshape(-1) for i in b.idx]).astype(jnp.float32)
+        for b in bp.buckets]
+
+
+def test_fuse_state_keeps_shaped_bucket_in_leaf_shape():
+    opt = adamw(1e-2, ema_decay=0.5, clip_norm=None)
+    bp = _mixed_plan()
+    assert [b.shaped for b in bp.buckets] == [True, False]
+    fused = fuse_state(opt.init(_mixed_params()), bp)
+    for tree in (fused.m, fused.v, fused.ema):
+        assert tree["bucket"][0].shape == (4, 8)     # the leaf's own shape
+        assert tree["bucket"][0].dtype == jnp.float32
+        assert tree["bucket"][1].shape == (18,)      # flat concatenation
+    # the flat variant of the same plan lays the one-member bucket flat
+    flat = fuse_state(opt.init(_mixed_params()), _mixed_plan(shaped=False))
+    assert flat.m["bucket"][0].shape == (32,)
+
+
+@pytest.mark.parametrize("shaped", [True, False])
+def test_fuse_unfuse_roundtrip_exact_mixed_plan(shaped):
+    opt = adamw(1e-2, ema_decay=0.5, clip_norm=None)
+    state = opt.init(_mixed_params())
+    # non-zero moments, so a misplaced segment cannot pass as zeros
+    state = state._replace(m=_mixed_grads(2.0), v=_mixed_grads(3.0))
+    bp = _mixed_plan(shaped)
+    fused = fuse_state(state, bp)
+    assert fused.m["leaf"]["a"] is None and fused.m["leaf"]["d"] is None
+    assert fused.m["leaf"]["c"] is not None
+    _assert_states_equal(state, unfuse_state(fused, bp))
+
+
+@pytest.mark.parametrize("make_opt", [
+    lambda: adamw(1e-2, b1=0.9, b2=0.95, weight_decay=0.1, clip_norm=1.0,
+                  ema_decay=0.9),
+    lambda: adamw(1e-2, weight_decay=0.0, clip_norm=None, ema_decay=0.0),
+    lambda: momentum(1e-1, mu=0.9, clip_norm=1.0, ema_decay=0.5),
+    lambda: adamw(1e-2, weight_decay=0.2, clip_norm=1.0, wd_mask={
+        "a": 0.5, "b": {"w": 1.0}, "c": 1.0, "d": 0.0}),
+])
+def test_fused_update_bit_identical_shaped_bucket(make_opt):
+    """A shaped bucket's chain is the per-leaf chain: over 3 steps the
+    fused apply on a plan with a shaped 2-D bucket and a two-member flat
+    bucket is bitwise equal to the per-param ``update`` and to the fused
+    apply with that bucket laid flat (params, moments, EMA, grad_norm)."""
+    opt = make_opt()
+    plans = {"shaped": _mixed_plan(), "flat": _mixed_plan(shaped=False)}
+    ref = opt.init(_mixed_params())
+    fused = {k: fuse_state(opt.init(_mixed_params()), bp)
+             for k, bp in plans.items()}
+    upd = jax.jit(opt.update)
+    upd_fused = {k: jax.jit(lambda s, g, bufs, bp=bp: opt.update_fused(
+        s, g, bufs, bp)) for k, bp in plans.items()}
+    for step in range(3):
+        g = _mixed_grads(scale=0.5 + step)      # crosses the clip threshold
+        ref, m_ref = upd(ref, g)
+        for k, bp in plans.items():
+            fused[k], m_fused = upd_fused[k](fused[k], g,
+                                             _exchanged_bufs(bp, g))
+            _assert_states_equal(ref, unfuse_state(fused[k], bp))
+            if "grad_norm" in m_ref:
+                assert float(m_ref["grad_norm"]) == \
+                    float(m_fused["grad_norm"])
+    assert fused["shaped"].m["bucket"][0].shape == (4, 8)
 
 
 def test_sgd_has_no_fused_path():
