@@ -5,7 +5,7 @@ from repro.configs.base import (
     get_config, all_configs, reduced, register,
 )
 
-# assigned architectures (10) — import for registration side effect
+# assigned architectures (10) and moonlight-16b-a3b — import for registration side effect
 from repro.configs import phi3_medium_14b      # noqa: F401
 from repro.configs import stablelm_12b         # noqa: F401
 from repro.configs import command_r_35b        # noqa: F401
@@ -16,6 +16,7 @@ from repro.configs import chameleon_34b        # noqa: F401
 from repro.configs import rwkv6_7b             # noqa: F401
 from repro.configs import hymba_1_5b           # noqa: F401
 from repro.configs import seamless_m4t_medium  # noqa: F401
+from repro.configs import moonlight_16b_a3b    # noqa: F401
 # paper's own models
 from repro.configs import parallax_lm          # noqa: F401
 from repro.configs import parallax_nmt         # noqa: F401
@@ -31,6 +32,7 @@ ALL_ARCHS = [
     "rwkv6-7b",
     "hymba-1.5b",
     "seamless-m4t-medium",
+    "moonlight-16b-a3b",
 ]
 
 PAPER_ARCHS = ["parallax-lm", "parallax-nmt"]

@@ -32,6 +32,20 @@ class ModelConfig:
     experts_per_token: int = 0
     moe_capacity_factor: float = 1.25
     shared_expert: bool = False     # llama4-style shared expert alongside routed
+    shared_d_ff: int = 0            # the shared expert's width (0: d_ff)
+    routed_scale: float = 1.0       # times the normalised top-k weights
+    # experts computed here: 0 = all, softmax-routed with capacity dispatch;
+    # n > 0 = the first n of n_experts (one chip's share of an
+    # expert-parallel layer), sigmoid-routed over all n_experts (DeepSeek-V3's
+    # gate) and dispatched dropless
+    experts_held: int = 0
+    first_k_dense: int = 0          # leading layers with a dense MLP
+    dense_d_ff: int = 0             # their width
+    # --- latent attention (MLA, DeepSeek-V2/V3); kv_lora_rank 0 = GQA ---
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     # --- SSM / hybrid ---
     ssm_state: int = 0
     conv_width: int = 4
@@ -65,6 +79,10 @@ class ModelConfig:
         return self.n_kv_heads * self.head_dim
 
     @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
     def attention_free(self) -> bool:
         return self.family == "ssm"
 
@@ -82,11 +100,18 @@ class ModelConfig:
             per_layer = 4 * d * d + 3 * d * f // 1 + 2 * d  # timemix + channelmix approx
             per_layer = 4 * d * d + 2 * d * f + 6 * d
         else:
-            attn = d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d
+            if self.mla:
+                h, r = self.n_heads, self.kv_lora_rank
+                attn = (d * h * (self.qk_nope_head_dim + self.qk_rope_head_dim)
+                        + d * (r + self.qk_rope_head_dim) + r
+                        + r * h * (self.qk_nope_head_dim + self.v_head_dim)
+                        + h * self.v_head_dim * d)
+            else:
+                attn = d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d
             if self.n_experts > 0:
                 ffn = self.n_experts * 3 * d * f
                 if self.shared_expert:
-                    ffn += 3 * d * f
+                    ffn += 3 * d * (self.shared_d_ff or f)
             else:
                 ffn = 3 * d * f
             per_layer = attn + ffn
@@ -94,6 +119,9 @@ class ModelConfig:
                 per_layer += 3 * d * d // 1 + d * self.ssm_state * 2   # ssm head branch
         layers = L + (self.enc_layers if self.is_encdec else 0)
         body = layers * per_layer
+        if self.first_k_dense:      # leading dense layers replace MoE ones
+            body += self.first_k_dense * (3 * d * self.dense_d_ff
+                                          - (per_layer - attn))
         if self.is_encdec:  # cross attention in decoder
             body += L * (d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d)
         return emb + body
@@ -157,6 +185,7 @@ class RunConfig:
     compute_dtype: str = "bfloat16"
     optimizer: str = "adamw"
     learning_rate: float = 1e-3
+    warmup_steps: int = 0             # >0: lr rises linearly from 0 to it
     weight_decay: float = 0.0
     clip_norm: float = 1.0
     ema_decay: float = 0.0            # 0 disables EMA shadow params
@@ -271,6 +300,16 @@ def reduced(cfg: ModelConfig, *, layers: int = 2, d_model: int = 64,
     if cfg.n_experts:
         kw.update(n_experts=min(experts, cfg.n_experts),
                   experts_per_token=min(cfg.experts_per_token, 2))
+        if cfg.experts_held:
+            kw.update(experts_held=max(kw["n_experts"] // 2, 1))
+        if cfg.shared_d_ff:
+            kw.update(shared_d_ff=d_ff)
+    if cfg.first_k_dense:
+        kw.update(first_k_dense=1, dense_d_ff=2 * d_ff)
+    if cfg.mla:
+        kw.update(kv_lora_rank=2 * head_dim, qk_nope_head_dim=head_dim,
+                  qk_rope_head_dim=head_dim // 2, v_head_dim=head_dim,
+                  n_kv_heads=heads)
     if cfg.ssm_state:
         kw.update(ssm_state=min(cfg.ssm_state, 8))
     if cfg.is_encdec:

@@ -2,8 +2,15 @@
 
 Implementations:
   naive    full (S,S) score materialization — small tests only.
-  chunked  online-softmax over KV blocks via lax.scan — the flash-attention
-           *algorithm* in pure XLA ops; memory O(S·C); dry-run default.
+  chunked  the flash-attention *algorithm* in pure XLA ops; the default.
+           Self-attention with one KV head per q head (``blocked_attention``)
+           runs over (query block, key block) pairs, causal pairs above the
+           diagonal skipped, with its own backward that recomputes each
+           pair's scores from the saved log-sum-exp: forward and backward
+           hold O(S·C) scores, and q/k and v head dims may differ. Other
+           cases (cross, offset or grouped queries) scan online-softmax over
+           KV blocks (``chunked_attention``), whose backward keeps each
+           block's scores.
   pallas   kernels/flash_attention.py (TPU target, validated interpret=True).
 
 Sharding (DESIGN.md): q heads padded to the model-axis size and sharded;
@@ -12,6 +19,7 @@ KV heads replicated (TP > n_kv); decode KV cache sequence-sharded over
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -128,6 +136,123 @@ def chunked_attention(q, k, v, *, causal: bool = True, chunk: int = 1024,
     return out.transpose(0, 2, 1, 3).astype(q.dtype)
 
 
+def _block_pairs(n: int, causal: bool):
+    """(query block, key block) index pairs, those wholly above the causal
+    diagonal left out."""
+    pairs = [(i, j) for i in range(n) for j in range(n) if j <= i or not causal]
+    return (jnp.asarray([i for i, _ in pairs], jnp.int32),
+            jnp.asarray([j for _, j in pairs], jnp.int32))
+
+
+def _pair_scores(qb, kb, i, j, block, causal, scale):
+    s = jnp.einsum("bhqd,bhkd->bhqk", qb, kb,
+                   preferred_element_type=jnp.float32) * scale
+    if causal:
+        qpos = i * block + jnp.arange(block)[:, None]
+        kpos = j * block + jnp.arange(block)[None, :]
+        s = jnp.where(qpos >= kpos, s, NEG_INF)
+    return s
+
+
+def _blk(x, i, block):
+    """Block ``i`` of the sequence axis (axis 2 of (B,H,S[,D]))."""
+    return jax.lax.dynamic_slice_in_dim(x, i * block, block, axis=2)
+
+
+def _put(x, upd, i, block):
+    return jax.lax.dynamic_update_slice_in_dim(x, upd, i * block, axis=2)
+
+
+def _blocked_fwd_core(q, k, v, causal, block):
+    """(B,H,S,Dk) x2, (B,H,S,Dv) -> out (B,H,S,Dv) f32, lse (B,H,S)."""
+    b, h, s, dk = q.shape
+    scale = dk ** -0.5
+
+    def body(carry, ij):
+        m, l, acc = carry
+        i, j = ij
+        sc = _pair_scores(_blk(q, i, block), _blk(k, j, block), i, j, block,
+                          causal, scale)
+        mb, lb, ab = _blk(m, i, block), _blk(l, i, block), _blk(acc, i, block)
+        m_new = jnp.maximum(mb, jnp.max(sc, axis=-1))
+        p = jnp.exp(sc - m_new[..., None])
+        corr = jnp.exp(mb - m_new)
+        l_new = lb * corr + jnp.sum(p, axis=-1)
+        a_new = ab * corr[..., None] + jnp.einsum(
+            "bhqk,bhkd->bhqd", p.astype(v.dtype), _blk(v, j, block),
+            preferred_element_type=jnp.float32)
+        return (_put(m, m_new, i, block), _put(l, l_new, i, block),
+                _put(acc, a_new, i, block)), None
+
+    carry = (jnp.full((b, h, s), NEG_INF, jnp.float32),
+             jnp.zeros((b, h, s), jnp.float32),
+             jnp.zeros((b, h, s, v.shape[-1]), jnp.float32))
+    (m, l, acc), _ = jax.lax.scan(body, carry,
+                                  _block_pairs(s // block, causal))
+    return acc / l[..., None], m + jnp.log(l)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _blocked(q, k, v, causal, block):
+    out, _ = _blocked_fwd_core(q, k, v, causal, block)
+    return out.astype(q.dtype)
+
+
+def _blocked_fwd(q, k, v, causal, block):
+    out, lse = _blocked_fwd_core(q, k, v, causal, block)
+    out = out.astype(q.dtype)
+    return out, (q, k, v, out, lse)
+
+
+def _blocked_bwd(causal, block, res, do):
+    q, k, v, out, lse = res
+    scale = q.shape[-1] ** -0.5
+    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), -1)
+
+    def body(carry, ij):
+        dq, dk, dv = carry
+        i, j = ij
+        qb, kb, vb, dob = (_blk(q, i, block), _blk(k, j, block),
+                           _blk(v, j, block), _blk(do, i, block))
+        sc = _pair_scores(qb, kb, i, j, block, causal, scale)
+        p = jnp.exp(sc - _blk(lse, i, block)[..., None])
+        dp = jnp.einsum("bhqd,bhkd->bhqk", dob, vb,
+                        preferred_element_type=jnp.float32)
+        ds = (p * (dp - _blk(delta, i, block)[..., None]) * scale
+              ).astype(q.dtype)
+        dv = _put(dv, _blk(dv, j, block) + jnp.einsum(
+            "bhqk,bhqd->bhkd", p.astype(do.dtype), dob,
+            preferred_element_type=jnp.float32), j, block)
+        dq = _put(dq, _blk(dq, i, block) + jnp.einsum(
+            "bhqk,bhkd->bhqd", ds, kb, preferred_element_type=jnp.float32),
+            i, block)
+        dk = _put(dk, _blk(dk, j, block) + jnp.einsum(
+            "bhqk,bhqd->bhkd", ds, qb, preferred_element_type=jnp.float32),
+            j, block)
+        return (dq, dk, dv), None
+
+    zeros = lambda x: jnp.zeros(x.shape, jnp.float32)  # noqa: E731
+    (dq, dk, dv), _ = jax.lax.scan(body, (zeros(q), zeros(k), zeros(v)),
+                                   _block_pairs(q.shape[2] // block, causal))
+    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+
+
+_blocked.defvjp(_blocked_fwd, _blocked_bwd)
+
+
+def blocked_attention(q, k, v, *, causal: bool = True,
+                      block: int = 1024) -> jax.Array:
+    """Self-attention, q/k (B,S,H,Dk), v (B,S,H,Dv) -> (B,S,H,Dv), over
+    ``block``-sized query and key blocks (the whole sequence when it does
+    not divide). Neither pass holds more than one pair's (B,H,C,C) scores."""
+    s = q.shape[1]
+    block = min(block, s)
+    if s % block:
+        block = s
+    t = lambda x: x.transpose(0, 2, 1, 3)  # noqa: E731
+    return t(_blocked(t(q), t(k), t(v), causal, block))
+
+
 def decode_attention(q, k_cache, v_cache, cache_len, *, qmap=None) -> jax.Array:
     """One-step attention against a (possibly sequence-sharded) KV cache.
 
@@ -158,6 +283,9 @@ def attention(q, k, v, *, impl: str = "chunked", causal: bool = True,
     if impl == "naive":
         return naive_attention(q, k, v, causal=causal, q_offset=q_offset,
                                qmap=qmap)
+    if impl == "chunked" and q_offset == 0 and qmap is None \
+            and q.shape[1] == k.shape[1]:
+        return blocked_attention(q, k, v, causal=causal, block=chunk)
     if impl == "chunked":
         return chunked_attention(q, k, v, causal=causal, chunk=chunk,
                                  q_offset=q_offset, qmap=qmap)

@@ -123,7 +123,7 @@ def build_model(cfg: ModelConfig, rt: Runtime) -> Model:
     # tokens are masked out of attention by the per-slot length, but they
     # WOULD corrupt a recurrent carry (ssm) — so those families stay on the
     # decode loop. hybrid carries an ssm state alongside its KV: same story.
-    paged = cfg.family in ("dense", "moe", "vlm")
+    paged = cfg.family in ("dense", "moe", "vlm") and not cfg.mla
 
     return Model(
         cfg=cfg, rt=rt,

@@ -15,6 +15,14 @@ execution plan:
 Dispatch is sort-based (argsort by expert id + positional capacity), not
 GShard one-hot-einsum — O(T·D + E·C·D) memory instead of O(T·E·C). Tokens
 are processed in groups (scan) to bound the dispatch buffers.
+
+A config with ``experts_held`` > 0 takes the held-experts layer instead
+(``held_moe_ffn``): one device's share of an expert-parallel layer. It
+routes over all ``n_experts`` and computes only the experts it holds,
+dropless: the (token, slot) pairs routed here are sorted by expert and go
+through grouped products (``lax.ragged_dot``) over exactly those rows;
+pairs routed to absent experts contribute nothing. No capacity, no padding
+to E×C, no all-to-all (the exchange between shares is not built yet).
 """
 from __future__ import annotations
 
@@ -22,11 +30,13 @@ import jax
 import jax.numpy as jnp
 
 from repro.compat import P, shard_map
-from repro.models.layers import ParamSpec
+from repro.models.layers import ParamSpec, swiglu
+from repro.utils.hlo import MOE_EXPERTS, MOE_ROUTE, MOE_SHARED
 
 
 def moe_specs(cfg, exec_mode: str) -> dict:
-    d, f, e = cfg.d_model, cfg.d_ff, cfg.n_experts
+    d, f = cfg.d_model, cfg.d_ff
+    e = cfg.experts_held or cfg.n_experts
     if exec_mode == "ep":
         axes_in = ("experts", None, None)
         axes_out = ("experts", None, None)
@@ -34,16 +44,111 @@ def moe_specs(cfg, exec_mode: str) -> dict:
         axes_in = (None, None, "mlp")
         axes_out = (None, "mlp", None)
     specs = {
-        "router": ParamSpec((d, e), (None, None), scale=0.02),
+        "router": ParamSpec((d, cfg.n_experts), (None, None), scale=0.02),
         "w_gate": ParamSpec((e, d, f), axes_in, fan_in_axes=(1,)),
         "w_up": ParamSpec((e, d, f), axes_in, fan_in_axes=(1,)),
         "w_down": ParamSpec((e, f, d), axes_out, fan_in_axes=(1,)),
     }
     if cfg.shared_expert:
-        specs["shared_gate"] = ParamSpec((d, f), (None, "mlp"), fan_in_axes=(0,))
-        specs["shared_up"] = ParamSpec((d, f), (None, "mlp"), fan_in_axes=(0,))
-        specs["shared_down"] = ParamSpec((f, d), ("mlp", None), fan_in_axes=(0,))
+        sf = cfg.shared_d_ff or f
+        specs["shared_gate"] = ParamSpec((d, sf), (None, "mlp"), fan_in_axes=(0,))
+        specs["shared_up"] = ParamSpec((d, sf), (None, "mlp"), fan_in_axes=(0,))
+        specs["shared_down"] = ParamSpec((sf, d), ("mlp", None), fan_in_axes=(0,))
     return specs
+
+
+def route(flat, router_w, cfg):
+    """Router over all ``n_experts`` in float32 (DeepSeek-V3's gate):
+    sigmoid scores, top-k, the chosen weights normalised to sum to one and
+    scaled by ``routed_scale``. -> weights (T,k) f32, ids (T,k)."""
+    logits = jnp.matmul(flat.astype(jnp.float32),
+                        router_w.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    w, eids = jax.lax.top_k(jax.nn.sigmoid(logits), cfg.experts_per_token)
+    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return w * cfg.routed_scale, eids
+
+
+@jax.custom_vjp
+def _permute(x, perm, inv):
+    """``x[perm]`` for a permutation ``perm`` whose inverse is ``inv``: the
+    backward is the gather ``g[inv]``, not the scatter-add a gather's
+    transpose is in general."""
+    return x[perm]
+
+
+def _permute_fwd(x, perm, inv):
+    return x[perm], inv
+
+
+def _permute_bwd(inv, g):
+    return g[inv], None, None
+
+
+_permute.defvjp(_permute_fwd, _permute_bwd)
+
+
+def held_moe_ffn(params: dict, x: jax.Array, *, cfg, rt,
+                 first_expert: int = 0):
+    """x: (B, S, D) -> (B, S, D), metrics: the routed experts
+    ``first_expert`` .. ``first_expert + experts_held`` (the leaves'
+    experts, in order) for the tokens routed to them, plus the shared
+    experts in full.
+
+    Counters: ``moe_held_rows`` (token, slot) pairs computed here;
+    ``moe_load_max`` the busiest held expert's rows over the mean held
+    expert's; ``moe_dropped`` 0, since nothing is dropped."""
+    b, s, d = x.shape
+    k, held = cfg.experts_per_token, cfg.experts_held
+    flat = x.reshape(b * s, d)
+    with jax.named_scope(MOE_ROUTE):
+        w, eids = route(flat, params["router"], cfg)
+        local = eids - first_expert
+        here = (local >= 0) & (local < held)
+        # absent experts sort last, as group ``held``, which no product reads
+        slot_e = jnp.where(here, local, held).reshape(-1)
+        order = jnp.argsort(slot_e, stable=True)
+        inv = jnp.zeros_like(order).at[order].set(
+            jnp.arange(order.shape[0], dtype=order.dtype))
+        sizes = jnp.sum(slot_e[:, None] == jnp.arange(held), axis=0,
+                        dtype=jnp.int32)
+        n_here = jnp.sum(sizes)
+        # ragged_dot leaves the rows past its last group undefined (on the
+        # TPU, in its backward too): they are zeroed before each product
+        # reads them, so no pass carries them into a gradient
+        valid = (jnp.arange(order.shape[0]) < n_here)[:, None]
+        rows = jnp.where(valid, _permute(jnp.repeat(flat, k, axis=0), order,
+                                         inv), 0).astype(rt.dtype)
+    with jax.named_scope(MOE_EXPERTS):
+        def gmm(a, w_):
+            return jax.lax.ragged_dot(a, w_.astype(rt.dtype), sizes)
+        h = jnp.where(valid, jax.nn.silu(gmm(rows, params["w_gate"]))
+                      * gmm(rows, params["w_up"]), 0)
+        ys = gmm(h, params["w_down"])
+    with jax.named_scope(MOE_ROUTE):
+        picked = jnp.where(here[..., None],
+                           _permute(ys, inv, order).reshape(b * s, k, d), 0)
+        out = jnp.sum((picked * w[..., None].astype(picked.dtype))
+                      .astype(jnp.float32), axis=1).astype(x.dtype)
+        out = out.reshape(b, s, d)
+    metrics = {"moe_dropped": jnp.int32(0), "moe_held_rows": n_here,
+               "moe_load_max": (jnp.max(sizes) * held).astype(jnp.float32)
+               / jnp.maximum(n_here, 1).astype(jnp.float32)}
+    if cfg.shared_expert:
+        out = out + _shared(params, x, rt).astype(out.dtype)
+    return out, metrics
+
+
+@jax.named_scope(MOE_SHARED)
+def _shared(params, x, rt):
+    from repro.core import sp
+    if sp.sp_active(rt, x):
+        g, u = sp.proj_in(rt, x, [params["shared_gate"],
+                                  params["shared_up"]], [True, True])
+        return sp.proj_out(rt, jax.nn.silu(g) * u, params["shared_down"])
+    return swiglu(x, params["shared_gate"], params["shared_up"],
+                  params["shared_down"],
+                  constrain=lambda h, _: rt.constrain(h, ("batch", None, "mlp")))
 
 
 def _dispatch_indices(eids, gates, n_experts, capacity):
@@ -120,6 +225,8 @@ def _moe_group(flat, router_w, w_gate, w_up, w_down, *, e, k, cf,
 def moe_ffn(params: dict, x: jax.Array, *, cfg, rt, exec_mode: str,
             group_tokens: int = 8192):
     """x: (B, S, D) -> (B, S, D), metrics."""
+    if cfg.experts_held:
+        return held_moe_ffn(params, x, cfg=cfg, rt=rt)
     b, s, d = x.shape
     e, k, cf = cfg.n_experts, cfg.experts_per_token, cfg.moe_capacity_factor
     mesh = rt.mesh
@@ -190,17 +297,7 @@ def moe_ffn(params: dict, x: jax.Array, *, cfg, rt, exec_mode: str,
 
     metrics = {"moe_aux": aux, "moe_dropped": dropped}
     if cfg.shared_expert:
-        from repro.core import sp
-        if sp.sp_active(rt, x):
-            g, u = sp.proj_in(rt, x, [params["shared_gate"],
-                                      params["shared_up"]], [True, True])
-            shared = sp.proj_out(rt, jax.nn.silu(g) * u,
-                                 params["shared_down"])
-        else:
-            h = jax.nn.silu(x @ params["shared_gate"]) * (x @ params["shared_up"])
-            h = rt.constrain(h, ("batch", None, "mlp"))
-            shared = h @ params["shared_down"]
-        out = out + shared.astype(out.dtype)
+        out = out + _shared(params, x, rt).astype(out.dtype)
     return out, metrics
 
 

@@ -20,6 +20,7 @@ from repro.models import moe as moe_mod
 from repro.models import rwkv as rwkv_mod
 from repro.models import ssm as ssm_mod
 from repro.models.layers import ParamSpec, rms_norm, swiglu, stack_tree
+from repro.utils.hlo import ATTENTION
 
 
 # ---------------------------------------------------------------------------
@@ -39,8 +40,26 @@ def attn_specs(cfg, rt) -> dict:
     }
 
 
-def mlp_specs(cfg) -> dict:
-    d, f = cfg.d_model, cfg.d_ff
+def mla_specs(cfg) -> dict:
+    """Multi-head latent attention (DeepSeek-V2/V3, no query compression):
+    queries of nope + rope dims per head; one latent of ``kv_lora_rank``
+    (RMS-normed) and one rope key shared by every head from ``wkv_a``; the
+    latent expanded to each head's nope key and value by ``wkv_b``."""
+    d, h, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    nope, rd, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    return {
+        "wq": ParamSpec((d, h * (nope + rd)), (None, "heads_hd"),
+                        fan_in_axes=(0,)),
+        "wkv_a": ParamSpec((d, r + rd), (None, None), fan_in_axes=(0,)),
+        "kv_norm": ParamSpec((r,), (None,), init="ones"),
+        "wkv_b": ParamSpec((r, h * (nope + vd)), (None, "heads_hd"),
+                           fan_in_axes=(0,)),
+        "wo": ParamSpec((h * vd, d), ("heads_hd", None), fan_in_axes=(0,)),
+    }
+
+
+def mlp_specs(cfg, width: int = 0) -> dict:
+    d, f = cfg.d_model, width or cfg.d_ff
     return {
         "w_gate": ParamSpec((d, f), (None, "mlp"), fan_in_axes=(0,)),
         "w_up": ParamSpec((d, f), (None, "mlp"), fan_in_axes=(0,)),
@@ -48,16 +67,20 @@ def mlp_specs(cfg) -> dict:
     }
 
 
-def layer_specs(cfg, rt, moe_exec: str) -> dict:
+def layer_specs(cfg, rt, moe_exec: str, dense: bool = False) -> dict:
+    """One layer's specs; ``dense``: a leading dense layer of a MoE model
+    (an MLP of ``dense_d_ff``)."""
     d = cfg.d_model
     if cfg.family == "ssm":
         return rwkv_mod.rwkv_block_specs(cfg)
     specs: dict[str, Any] = {
         "ln1": ParamSpec((d,), (None,), init="ones"),
-        "attn": attn_specs(cfg, rt),
+        "attn": mla_specs(cfg) if cfg.mla else attn_specs(cfg, rt),
         "ln2": ParamSpec((d,), (None,), init="ones"),
     }
-    if cfg.family == "moe":
+    if dense:
+        specs["mlp"] = mlp_specs(cfg, cfg.dense_d_ff)
+    elif cfg.family == "moe":
         specs["moe"] = moe_mod.moe_specs(cfg, moe_exec)
     else:
         specs["mlp"] = mlp_specs(cfg)
@@ -73,9 +96,13 @@ def model_specs(cfg, rt) -> dict:
     specs = {
         "embed": ParamSpec((vp, d), ("vocab", "embed"), init="embed",
                            sparse=True),
-        "layers": stack_tree(layer_specs(cfg, rt, moe_exec), cfg.n_layers),
+        "layers": stack_tree(layer_specs(cfg, rt, moe_exec),
+                             cfg.n_layers - cfg.first_k_dense),
         "final_norm": ParamSpec((d,), (None,), init="ones"),
     }
+    if cfg.first_k_dense:
+        specs["dense_layers"] = stack_tree(
+            layer_specs(cfg, rt, moe_exec, dense=True), cfg.first_k_dense)
     if not cfg.tie_embeddings:
         specs["head"] = ParamSpec((vp, d), ("vocab", "embed"), scale=0.02)
     return specs
@@ -181,6 +208,42 @@ def attn_block(p, x, *, cfg, rt, positions, layer_cache=None, cache_len=None,
     return out, new_cache
 
 
+def mla_block(p, x, *, cfg, rt, positions):
+    """Latent self-attention sub-block (training and prefill; no cache).
+    Scores use the q/k head dim nope + rope (scale its -1/2 power); values
+    have ``v_head_dim``."""
+    b, s, _ = x.shape
+    h, r = cfg.n_heads, cfg.kv_lora_rank
+    nope, rd, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    q = (x @ p["wq"]).reshape(b, s, h, nope + rd)
+    ckv = x @ p["wkv_a"]
+    kv = (rms_norm(ckv[..., :r], p["kv_norm"], cfg.norm_eps) @ p["wkv_b"]
+          ).reshape(b, s, h, nope + vd)
+    q_pe = attn_mod.rope(q[..., nope:], positions, cfg.rope_theta)
+    k_pe = attn_mod.rope(ckv[..., None, r:], positions, cfg.rope_theta)
+    q = jnp.concatenate([q[..., :nope], q_pe], axis=-1)
+    k = jnp.concatenate([kv[..., :nope],
+                         jnp.broadcast_to(k_pe, (b, s, h, rd))], axis=-1)
+    q = rt.constrain(q, ("batch", None, "q_heads", None))
+    with jax.named_scope(ATTENTION):
+        out = attn_mod.attention(q, k, kv[..., nope:],
+                                 impl=rt.run_cfg.attention_impl, causal=True,
+                                 chunk=rt.run_cfg.attention_chunk)
+    out = rt.constrain(out, ("batch", None, "q_heads", None))
+    return out.reshape(b, s, h * vd) @ p["wo"]
+
+
+def _at_compute_dtype(p, rt):
+    """A layer's floating parameters at the compute dtype, so that float32
+    parameters (master weights) under bf16 matmuls keep the residual
+    stream in bf16; the router stays as held, since it runs in float32."""
+    out = jax.tree.map(lambda a: a.astype(rt.dtype)
+                       if jnp.issubdtype(a.dtype, jnp.floating) else a, p)
+    if "router" in p.get("moe", {}):
+        out["moe"] = {**out["moe"], "router": p["moe"]["router"]}
+    return out
+
+
 def decoder_layer(p, x, *, cfg, rt, positions, layer_cache=None,
                   cache_len=None, moe_exec="tp", collect_kv=False):
     """Pre-norm decoder layer; returns (x, new_cache, metrics)."""
@@ -197,6 +260,12 @@ def decoder_layer(p, x, *, cfg, rt, positions, layer_cache=None,
         ssm_out, h_ssm = ssm_mod.ssm_mix(p["ssm"], h, h_ssm, cfg=cfg, rt=rt)
         attn_out = (attn_out + ssm_out) * 0.5
         new_cache = (*new_kv, h_ssm) if new_kv is not None else None
+    elif cfg.mla:
+        if layer_cache is not None or collect_kv:
+            raise NotImplementedError("latent attention has no decode cache")
+        attn_out = mla_block(p["attn"], h, cfg=cfg, rt=rt,
+                             positions=positions)
+        new_cache = None
     else:
         attn_out, new_cache = attn_block(
             p["attn"], h, cfg=cfg, rt=rt, positions=positions,
@@ -206,7 +275,7 @@ def decoder_layer(p, x, *, cfg, rt, positions, layer_cache=None,
     x = rt.constrain(x, rt_residual_axes(rt, x))
 
     h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
-    if cfg.family == "moe":
+    if "moe" in p:
         ffn_out, m = moe_mod.moe_ffn(p["moe"], h2, cfg=cfg, rt=rt,
                                      exec_mode=moe_exec)
         metrics.update(m)
@@ -284,6 +353,8 @@ def forward(params, tokens, *, cfg, rt, cache=None, cache_len=None,
     insertion into a live decode cache.
     """
     moe_exec = moe_mod.pick_exec_mode(cfg, rt) if cfg.n_experts else "tp"
+    if cfg.first_k_dense and (cache is not None or collect_kv):
+        raise NotImplementedError("leading dense layers have no decode cache")
     b, s = tokens.shape
     ctx = rt.embed_ctx()
     x, emetrics = emb.lookup(params["embed"], tokens, ctx=ctx,
@@ -312,7 +383,7 @@ def forward(params, tokens, *, cfg, rt, cache=None, cache_len=None,
             x, new_carry = rwkv_mod.rwkv_block(p, x, layer_cache, cfg=cfg, rt=rt)
             return x, (new_carry, {})
         x, new_cache, metrics = decoder_layer(
-            p, x, cfg=cfg, rt=rt, positions=positions,
+            _at_compute_dtype(p, rt), x, cfg=cfg, rt=rt, positions=positions,
             layer_cache=layer_cache, cache_len=cache_len, moe_exec=moe_exec,
             collect_kv=collect_kv)
         return x, (new_cache, metrics)
@@ -341,9 +412,13 @@ def forward(params, tokens, *, cfg, rt, cache=None, cache_len=None,
             return x, metrics
         if remat in ("block", "full"):
             no_cache_fn = jax.checkpoint(no_cache_fn, policy=policy)
+        if "dense_layers" in params:
+            x, _ = jax.lax.scan(no_cache_fn, x, params["dense_layers"])
         x, metrics = jax.lax.scan(no_cache_fn, x, params["layers"])
         new_cache = None
-    metrics = jax.tree.map(lambda a: jnp.sum(a, axis=0), metrics) if metrics else {}
+    # per-layer counters add up over layers; a ``*_max`` takes the largest
+    metrics = {k: (jnp.max if k.endswith("_max") else jnp.sum)(a, axis=0)
+               for k, a in metrics.items()}
     metrics.update(emetrics)
 
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)

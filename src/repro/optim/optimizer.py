@@ -501,14 +501,24 @@ def sgd(lr: float | Callable = 1e-2, clip_norm: Optional[float] = None,
     return Optimizer("sgd", init, update)
 
 
+def warmup(lr: float, steps: int) -> float | Callable:
+    """``lr``, or for ``steps`` > 0 the schedule that rises linearly from 0
+    and reaches ``lr`` at update ``steps`` (updates count from 1)."""
+    if not steps:
+        return lr
+    return lambda step: lr * jnp.minimum(1.0, step.astype(jnp.float32)
+                                         / steps)
+
+
 def make_optimizer(rt) -> Optimizer:
     rc = rt.run_cfg
+    lr = warmup(rc.learning_rate, rc.warmup_steps)
     if rc.optimizer == "adamw":
-        return adamw(rc.learning_rate, weight_decay=rc.weight_decay,
+        return adamw(lr, weight_decay=rc.weight_decay,
                      clip_norm=rc.clip_norm, ema_decay=rc.ema_decay, rt=rt)
     if rc.optimizer == "momentum":
-        return momentum(rc.learning_rate, clip_norm=rc.clip_norm,
+        return momentum(lr, clip_norm=rc.clip_norm,
                         ema_decay=rc.ema_decay, rt=rt)
     if rc.optimizer == "sgd":
-        return sgd(rc.learning_rate, clip_norm=rc.clip_norm, rt=rt)
+        return sgd(lr, clip_norm=rc.clip_norm, rt=rt)
     raise ValueError(f"unknown optimizer {rc.optimizer!r}")

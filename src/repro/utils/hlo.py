@@ -458,6 +458,14 @@ def collective_bytes_by_kind(hlo_text: str) -> dict[str, float]:
 FORWARD, BACKWARD, OPTIMIZER, EXCHANGE = (
     "forward", "backward", "optimizer", "exchange")
 
+# Scopes inside the model, nested in ``forward`` (their backward ops carry
+# them under ``transpose(``): the attention core (models/attention.py
+# callers), and the MoE layer's routing with its sort and scatter-back, its
+# grouped expert products and its shared experts (models/moe.py).
+ATTENTION, MOE_ROUTE, MOE_EXPERTS, MOE_SHARED = (
+    "attention", "moe_route", "moe_experts", "moe_shared")
+MODEL_SCOPES = (ATTENTION, MOE_ROUTE, MOE_EXPERTS, MOE_SHARED)
+
 
 def _scope_in(name: str):
     # a path component is the scope itself or a transform of it,
@@ -487,15 +495,29 @@ _CALLS = re.compile(r"\b(?:calls|body)=%?([\w.\-]+)")
 _REF = re.compile(r"%([\w.\-]+)")
 
 
-def step_phases(hlo_text: str) -> dict:
+def model_scope(op_name: str) -> str | None:
+    """The innermost of ``MODEL_SCOPES`` that an op's ``op_name`` names,
+    else None."""
+    best, at = None, -1
+    for scope in MODEL_SCOPES:
+        for m in _scope_in(scope).finditer(op_name):
+            if m.start() > at:
+                best, at = scope, m.start()
+    return best
+
+
+def step_phases(hlo_text: str, classify=step_phase,
+                named: bool = False) -> dict:
     """``{instruction name: phase}`` for the instructions of a compiled
-    module's text (``.compile().as_text()``) that have one. An instruction
-    takes the phase of its own op_name. One whose op_name names no phase,
-    or that has none (a backend may leave a fusion, a convert or a layout
-    copy without metadata), takes that of the computation it calls (its
-    root's, else its instructions' most common), else that of its first
-    operand that has one, else that of its first user that has one (a
-    buffer of zeros is the work of the phase that fills it)."""
+    module's text (``.compile().as_text()``) that have one, by ``classify``
+    of their ``op_name``. An instruction takes the phase of its own
+    op_name. One whose op_name names no phase (unless ``named``: then it
+    has none), or that has no op_name (a backend may leave a fusion, a
+    convert or a layout copy without metadata), takes that of the
+    computation it calls (its root's, else its instructions' most common),
+    else that of its first operand that has one, else that of its first
+    user that has one (a buffer of zeros is the work of the phase that
+    fills it)."""
     out, comps, cur, users, todo = {}, {}, None, {}, []
     for line in hlo_text.splitlines():
         if line.rstrip().endswith("{") and " = " not in line:
@@ -510,7 +532,9 @@ def step_phases(hlo_text: str) -> dict:
         for r in refs:
             users.setdefault(r, []).append(name)
         op = _OP_NAME.search(rest)
-        phase = step_phase(op.group(1)) if op else None
+        phase = classify(op.group(1)) if op else None
+        if phase is None and op and named:
+            continue
         if phase is None:
             call = _CALLS.search(rest)
             called = comps.get(call.group(1)) if call else None
@@ -532,4 +556,20 @@ def step_phases(hlo_text: str) -> dict:
                      None)
         if phase is not None:
             out[name] = phase
+    return out
+
+
+def split_device_time(op_seconds: dict, hlo_text: str) -> dict:
+    """``{instruction name: device seconds}`` of a traced step, joined to
+    its compiled text -> seconds by ``phase`` and, inside the model's
+    scopes, by ``phase/scope`` (an op named outside every scope stays in
+    its phase); ops of no phase count under ``unattributed``."""
+    phases = step_phases(hlo_text)
+    scopes = step_phases(hlo_text, model_scope, named=True)
+    out = {}
+    for name, sec in op_seconds.items():
+        key = phases.get(name, "unattributed")
+        if name in scopes:
+            key += "/" + scopes[name]
+        out[key] = out.get(key, 0.0) + sec
     return out

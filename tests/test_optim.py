@@ -7,7 +7,8 @@ import pytest
 
 from repro.core.buckets import Bucket, BucketPlan
 from repro.optim.optimizer import adamw, momentum, sgd, global_norm, \
-    clip_by_global_norm, bucket_segments, fuse_state, is_fused, unfuse_state
+    clip_by_global_norm, bucket_segments, fuse_state, is_fused, \
+    unfuse_state, warmup
 
 
 def _params():
@@ -42,6 +43,20 @@ def test_adamw_matches_reference():
         got = state2.params["a"] if name == "a" else state2.params["b"]["w"]
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=1e-5)
+
+
+def test_warmup_rises_linearly_to_the_lr():
+    """No warmup keeps the plain float (the step compiles as before); a
+    warmup of 4 updates moves update t by t/4 of the lr, then by the lr."""
+    assert warmup(1e-2, 0) == 1e-2
+    sched = warmup(1e-2, 4)
+    got = [float(sched(jnp.int32(t))) for t in (1, 2, 4, 9)]
+    np.testing.assert_allclose(got, [2.5e-3, 5e-3, 1e-2, 1e-2], rtol=1e-6)
+    # through adamw: the first update (sign-like) moves each element by lr/4
+    opt = adamw(sched, clip_norm=None)
+    state2, _ = opt.update(opt.init(_params()), _grads())
+    moved = np.abs(np.asarray(state2.params["a"]) - np.asarray(_params()["a"]))
+    np.testing.assert_allclose(moved, 2.5e-3, rtol=1e-4)
 
 
 def test_clip_by_global_norm_matches_formula():
